@@ -180,7 +180,7 @@ def smoke_calibration():
         t_elem=1e-9,
         t_coll=4e-9,
         t_h2d=2e-9,
-        device_kind=dev.platform,
+        device_kind=dev.device_kind,
         device_count=jax.device_count(),
     )
 

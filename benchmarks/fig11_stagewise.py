@@ -22,8 +22,7 @@ DEPTHS = (1, 2, 3)
 
 
 def _divide_phase(a, b, depth):
-    ac = jnp.asarray(STRASSEN.a_coef)
-    bc = jnp.asarray(STRASSEN.b_coef)
+    ac, bc = STRASSEN.a_coef, STRASSEN.b_coef
     ta, tb = a[None], b[None]
     for _ in range(depth):
         ta = divide_level(ta, ac)
@@ -36,7 +35,7 @@ def _leaf_phase(ta, tb):
 
 
 def _combine_phase(prod, depth):
-    cc = jnp.asarray(STRASSEN.c_coef)
+    cc = STRASSEN.c_coef
     for _ in range(depth):
         prod = combine_level(prod, cc)
     return prod[0]

@@ -1,10 +1,10 @@
 """Paper Fig. 12: scalability with executor count.
 
-This container has ONE physical core, so wall-clock cannot show real
-speedup. We measure what IS measurable from the compiled artifact — the
-per-device work division — by lowering the distributed Strassen under
-meshes of 1..8 host devices in a SUBPROCESS (device count is locked at
-jax init) and reporting per-device HLO FLOPs. Ideal scaling halves
+Wall-clock on forced host devices cannot show real speedup, so this
+measures what IS measurable from the compiled artifact — the per-device
+work division — by lowering the distributed Strassen under meshes of 1..8
+CPU host devices in a SUBPROCESS (device count is locked at jax init) and
+reporting per-device HLO FLOPs. Ideal scaling halves
 per-device FLOPs per doubling; the derived column reports the achieved
 parallel efficiency vs T(1)/n, exactly the paper's ideal-line comparison.
 """
@@ -43,6 +43,9 @@ def run():
     for n_dev in (1, 2, 4, 8):
         env = dict(os.environ)
         env["PYTHONPATH"] = "src"
+        # The children only count HLO FLOPs on forced host devices; pinned to
+        # the CPU they never contend for an accelerator the parent holds.
+        env["JAX_PLATFORMS"] = "cpu"
         out = subprocess.run(
             [sys.executable, "-c", _CHILD, str(n_dev)],
             capture_output=True, text=True, env=env, cwd=os.path.dirname(__file__) + "/..",

@@ -25,8 +25,7 @@ def run():
     rows = []
     for n in SIZES:
         a, b = rand((n, n)), rand((n, n))
-        ac = jnp.asarray(STRASSEN.a_coef)
-        bc = jnp.asarray(STRASSEN.b_coef)
+        ac, bc = STRASSEN.a_coef, STRASSEN.b_coef
         for depth in DEPTHS:
             ta, tb = a[None], b[None]
             for _ in range(depth):

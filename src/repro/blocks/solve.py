@@ -380,12 +380,16 @@ class SolveScheduler:
         import jax.numpy as jnp
         import jax.scipy.linalg as jsl
 
+        from repro.core.backend import precision_scope, resolve_precision
+
         arrays = [ops[name] for name in plan.operands]
         in_bytes = sum(x.nbytes for x in arrays)
+        # The dense leaf runs at the precision of the backend its recursive
+        # multiplies use, so the whole inverse is at one precision.
         with tr.span(
             f"leaf.{plan.leaf_kind}", cat="oot", op=plan.op, tag=tag,
             h2d_bytes=in_bytes,
-        ) as lsp:
+        ) as lsp, precision_scope(resolve_precision(self.backend)):
             devs = [jax.device_put(np.ascontiguousarray(x)) for x in arrays]
             if plan.leaf_kind == "inv":
                 out = jnp.linalg.inv(devs[0])

@@ -7,7 +7,6 @@ Public API:
   backend       — pluggable matmul routing used by all model layers
   cost_model    — the paper's §IV stage-wise analytical cost model
 """
-from repro.core import compat  # noqa: F401  (applies jax version shims)
 from repro.core.coefficients import STRASSEN, WINOGRAD, NAIVE8, Scheme, get_scheme
 from repro.core.strassen import (
     strassen_matmul,

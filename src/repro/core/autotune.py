@@ -291,7 +291,7 @@ def calibrate(sample_dim: int = 256, repeats: int = 3) -> Calibration:
     t_leaf = _time_best(lambda: jax.block_until_ready(leaf(a, b)), repeats)
     t_flop = t_leaf / (rank * 2.0 * d**3)
 
-    coef = jnp.asarray(scheme.a_coef)
+    coef = scheme.a_coef
     div = jax.jit(lambda x: divide_level(x, coef))
     t_div = _time_best(lambda: jax.block_until_ready(div(a)), repeats)
     # divide_level: (rank, d, d) -> (rank*rank, d/2, d/2) output elements.
@@ -302,7 +302,7 @@ def calibrate(sample_dim: int = 256, repeats: int = 3) -> Calibration:
     return Calibration(
         t_flop=float(t_flop),
         t_elem=float(t_elem),
-        device_kind=dev.platform,
+        device_kind=dev.device_kind,
         device_count=jax.device_count(),
         t_coll=float(calibrate_collective(repeats=repeats)),
         t_h2d=float(calibrate_h2d(repeats=repeats)),
@@ -640,9 +640,9 @@ def execute(
         )
         return jnp.asarray(out)
     if cand.kind == FUSED_KIND:
-        from repro.kernels.strassen.ops import strassen_matmul_fused
+        from repro.kernels.strassen.ops import strassen_matmul_fused_padded
 
-        return strassen_matmul_fused(
+        return strassen_matmul_fused_padded(
             a, b, depth=cand.depth, scheme_name=cand.scheme, precision=precision
         )
     if cand.kind in LOCAL_SCHEMES:
@@ -894,6 +894,18 @@ def reset_telemetry() -> Telemetry:
     return _TELEMETRY
 
 
+def _cached_calibration(cache: Optional[TuningCache], dev) -> Optional[Calibration]:
+    """The cache's calibration if it was fitted on this device kind.
+
+    A cache file can travel between hosts; constants fitted on another
+    chip generation (or on CPU) must not price this one's candidates.
+    """
+    calib = cache.calibration if cache is not None else None
+    if calib is not None and calib.device_kind == dev.device_kind:
+        return calib
+    return None
+
+
 _PROCESS_CACHES: Dict[str, TuningCache] = {}
 
 
@@ -963,7 +975,7 @@ def autotune(
         device_count = 1
         topo = "local"
     key_kwargs = dict(
-        device_kind=dev.platform,
+        device_kind=dev.device_kind,
         device_count=device_count,
         schemes=schemes,
         min_dim=min_dim,
@@ -1008,7 +1020,7 @@ def autotune(
             )
             return decision
 
-    calib = calibration or (cache.calibration if cache else None) or get_calibration()
+    calib = calibration or _cached_calibration(cache, dev) or get_calibration()
     cands = enumerate_candidates(
         m, k, n, schemes=schemes, max_depth=max_depth, min_dim=min_dim, mesh=mesh,
         oot_budget=oot_budget, dtype=dtype,
@@ -1060,7 +1072,7 @@ def autotune(
         source="measured" if measure else "predicted",
     )
     if cache is not None:
-        cache.calibration = cache.calibration or calib
+        cache.calibration = _cached_calibration(cache, dev) or calib
         # Predicted decisions are shape-only by construction, so a tagged
         # resolution stores under the shape-only key — every other site of
         # the same shape then hits via the fallback instead of duplicating
@@ -1230,7 +1242,7 @@ def autotune_solver(
     leaf_kind = "inv" if op == "inverse" else "trsm_lower"
     key = cache_key(
         n, n, n if nrhs is None else nrhs, dtype,
-        device_kind=dev.platform, device_count=1,
+        device_kind=dev.device_kind, device_count=1,
         schemes=(scheme,), min_dim=0, max_depth=max_depth,
         topo=family, site=site, oot_budget=oot_budget,
     )
@@ -1254,7 +1266,7 @@ def autotune_solver(
             )
             return decision
 
-    calib = calibration or (cache.calibration if cache else None) or get_calibration()
+    calib = calibration or _cached_calibration(cache, dev) or get_calibration()
     if oot_budget:
         d_min = solver_min_depth_for_budget(
             n, oot_budget, dtype, nrhs=nrhs, leaf_kind=leaf_kind,
@@ -1277,7 +1289,7 @@ def autotune_solver(
         predicted_s=float(predicted), source="predicted",
     )
     if cache is not None:
-        cache.calibration = cache.calibration or calib
+        cache.calibration = _cached_calibration(cache, dev) or calib
         cache.put(key, decision)
         cache.save()
     terms = predict_solver_terms(

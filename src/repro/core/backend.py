@@ -13,6 +13,7 @@ naive path (exactly like Stark's ``threshold`` leaf cutoff).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import os
@@ -43,6 +44,7 @@ __all__ = [
     "set_default_matmul_precision",
     "default_matmul_precision",
     "resolve_precision",
+    "precision_scope",
 ]
 
 # The registered routing kinds: every MatmulBackend.kind (and every CLI
@@ -116,6 +118,17 @@ def default_matmul_precision() -> Optional[str]:
 def resolve_precision(backend: "MatmulBackend") -> Optional[str]:
     """The precision a backend's matmuls run at: its own, else the default."""
     return backend.precision if backend.precision is not None else _DEFAULT_PRECISION
+
+
+def precision_scope(precision: Optional[str]):
+    """Run the jnp ops that take no precision argument (the linalg leaves:
+    ``inv``, ``solve_triangular``) at ``precision`` — the same knob the
+    matmuls of that backend run at. None leaves the platform default;
+    'fastest' is the platform default too (jax's scope has no such name).
+    """
+    if precision is None or precision == "fastest":
+        return contextlib.nullcontext()
+    return jax.default_matmul_precision(precision)
 
 
 def enable_xla_async_flags(flags: Tuple[str, ...] = XLA_ASYNC_FLAGS) -> bool:
@@ -443,14 +456,14 @@ def _matmul_routed(x, w, backend, w_logical, site, lead, m, k, n):
             # Pin the kernel's boundary shardings to the caller's
             # tensor-parallel layout — same rationale as the unfused
             # branch's per-level hooks: GSPMD loses sharding at quadrant
-            # reshapes. At depth 1 (no outer einsum levels) the boundary
+            # reshapes. At depth 1 (no outer BFS levels) the boundary
             # fully determines the pallas call's operand layout.
             from repro.models.sharding import constrain
 
             w_in, w_out = w_logical
             x2 = constrain(x2, "batch", None)
             w = constrain(w, w_in, w_out)
-        out = strassen_ops.strassen_matmul_fused(
+        out = strassen_ops.strassen_matmul_fused_padded(
             x2, w, depth=depth, precision=precision
         )
         if w_logical is not None:
@@ -555,7 +568,8 @@ def inverse(
         traced=traced,
     ):
         if kind == "dense":
-            return jnp.linalg.inv(a)
+            with precision_scope(resolve_precision(backend)):
+                return jnp.linalg.inv(a)
         _solver_jit_guard("inverse", a)
         import numpy as np
 
@@ -619,7 +633,8 @@ def solve_triangular(
         if kind == "dense":
             import jax.scipy.linalg as jsl
 
-            return jsl.solve_triangular(l, b, lower=lower)
+            with precision_scope(resolve_precision(backend)):
+                return jsl.solve_triangular(l, b, lower=lower)
         _solver_jit_guard("solve_triangular", l, b)
         import numpy as np
 

@@ -1,111 +1,94 @@
-"""JAX version compatibility shims.
+"""Thin wrappers over the JAX surfaces this repo pins a behaviour on.
 
-The repo targets the current JAX API (``jax.shard_map``,
-``jax.sharding.AxisType``, dict-returning ``Compiled.cost_analysis``) but
-must also run on jax 0.4.x, where shard_map lives in ``jax.experimental``,
-meshes have no axis types, and cost_analysis returns a one-element list.
-Everything that touches one of those surfaces goes through this module so
-the version probe happens in exactly one place.
+The repo targets the installed JAX (0.9): ``jax.shard_map``, typed meshes
+(``jax.sharding.AxisType``) and dict-returning ``Compiled.cost_analysis``.
+Mesh construction, replication-unchecked shard_map, the cost-analysis
+flattening and the Pallas-leaf probe go through this module so each
+choice is made in exactly one place.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Sequence
+from typing import Any, Dict, Sequence
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = [
     "make_mesh",
     "shard_map",
     "compiled_cost_analysis",
-    "has_axis_types",
     "pallas_leaf_mode",
 ]
 
-# jax < 0.5 defaults to the legacy non-partitionable threefry, whose values
-# change when the consuming computation is sharded under GSPMD — a jitted
-# sharded init then disagrees with the same init on one device. Newer jax
-# defaults this flag on; pin it so both versions behave identically.
-try:
-    jax.config.update("jax_threefry_partitionable", True)
-except Exception:  # flag removed once the legacy path is gone
-    pass
-
-# jax >= 0.5 exposes explicit/auto axis types; 0.4.x meshes are untyped
-# (equivalent to Auto everywhere, which is what this repo uses).
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
-def has_axis_types() -> bool:
-    return _AXIS_TYPE is not None
-
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], **kwargs):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _AXIS_TYPE is not None:
-        kwargs.setdefault("axis_types", (_AXIS_TYPE.Auto,) * len(tuple(axis_names)))
+    """``jax.make_mesh`` with Auto axis types (what every sharding rule here
+    assumes: GSPMD propagates, nothing is explicit-typed)."""
+    kwargs.setdefault("axis_types", (AxisType.Auto,) * len(tuple(axis_names)))
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Replication-unchecked shard_map across the 0.4/0.5+ API split.
+    """Replication-unchecked ``jax.shard_map``.
 
     The Strassen shardmap bodies psum partial products whose replication
-    XLA cannot infer, so both the new ``check_vma`` and the old
-    ``check_rep`` verifier must be off.
+    XLA cannot infer, so the ``check_vma`` verifier must be off.
     """
-    new_sm = getattr(jax, "shard_map", None)
-    if new_sm is not None:
-        return new_sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as old_sm
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
-    return old_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+
+# The probe runs the fused leaf at its real tile size: a probe smaller than
+# the tiles production calls use would pass where they fail.
+_PROBE_TILE = 256
 
 
 @functools.lru_cache(None)
 def pallas_leaf_mode() -> str:
-    """How the fused Strassen Pallas leaf can run on this host.
+    """How the fused Strassen Pallas leaf runs on this host.
 
     Returns one of:
       'compiled'  — a TPU backend is present; the kernel compiles via Mosaic.
       'interpret' — no TPU, but interpret-mode ``pallas_call`` works (CPU
                     hosts, including host-platform multi-device test meshes).
-      'none'      — pallas is unavailable or broken in this jax build;
-                    callers must use the jnp reference path.
+      'none'      — no TPU and pallas is unavailable or broken in this jax
+                    build; callers must use the jnp reference path.
 
-    The probe actually executes a tiny fused kernel rather than sniffing
-    versions: autotune enumeration gates ``strassen_fused`` candidates on
-    this answer, so "the leaf compiles" must mean a real end-to-end run.
-    Cached per process (device topology is fixed after jax init).
+    The probe executes one fused kernel at the production tile size rather
+    than sniffing versions: autotune enumeration gates ``strassen_fused``
+    candidates on this answer. On a TPU a failed probe raises — a Mosaic
+    compile error there is a bug to surface, never a reason to route
+    around the kernel. Cached per process (the backend is fixed after jax
+    init).
     """
-    try:
-        import jax.numpy as jnp
+    import jax.numpy as jnp
 
+    on_tpu = jax.default_backend() == "tpu"
+
+    def probe():
         from repro.kernels.strassen.strassen import strassen1_matmul_pallas
 
-        on_tpu = jax.default_backend() == "tpu"
-        x = jnp.ones((1, 4, 128, 128), jnp.float32)
+        t = _PROBE_TILE
+        x = jnp.ones((1, 4, t, t), jnp.float32)
         jax.block_until_ready(
             strassen1_matmul_pallas(
-                x, x, block_m=128, block_n=128, block_k=128, interpret=not on_tpu
+                x, x, block_m=t, block_n=t, block_k=t, interpret=not on_tpu
             )
         )
-        return "compiled" if on_tpu else "interpret"
+
+    if on_tpu:
+        probe()
+        return "compiled"
+    try:
+        probe()
     except Exception:
         return "none"
+    return "interpret"
 
 
 def compiled_cost_analysis(compiled: Any) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version.
-
-    jax 0.4.x returns ``[{...}]`` (one dict per partition), newer versions
-    return the dict directly, and some backends return None.
-    """
-    cost: Optional[Any] = compiled.cost_analysis()
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
+    """``Compiled.cost_analysis()`` as a flat dict ({} where the backend
+    reports none)."""
+    return dict(compiled.cost_analysis() or {})

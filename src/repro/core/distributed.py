@@ -85,24 +85,27 @@ def strassen_bfs_sharded(
     a = _constraint(a, mesh, row_spec)
     b = _constraint(b, mesh, row_spec)
 
-    a_coef = jnp.asarray(scheme.a_coef)
-    b_coef = jnp.asarray(scheme.b_coef)
-    c_coef = jnp.asarray(scheme.c_coef)
+    a_coef, b_coef, c_coef = scheme.a_coef, scheme.b_coef, scheme.c_coef
 
     ta, tb = a[None], b[None]
     for _ in range(depth):
         ta = _constraint(_s.divide_level(ta, a_coef), mesh, batch_spec)
         tb = _constraint(_s.divide_level(tb, b_coef), mesh, batch_spec)
 
+    out_dtype = jnp.result_type(a, b)
+    acc_dtype = _s.accumulation_dtype(out_dtype)
     if leaf_fn is None:
-        prod = jnp.einsum("mij,mjk->mik", ta, tb, precision=precision)
+        prod = jnp.einsum(
+            "mij,mjk->mik", ta, tb, precision=precision,
+            preferred_element_type=acc_dtype,
+        )
     else:
-        prod = leaf_fn(ta, tb)
+        prod = leaf_fn(ta, tb).astype(acc_dtype)
     prod = _constraint(prod, mesh, batch_spec)
 
     for _ in range(depth):
         prod = _constraint(_s.combine_level(prod, c_coef), mesh, batch_spec)
-    return _constraint(prod[0], mesh, row_spec)
+    return _constraint(prod[0].astype(out_dtype), mesh, row_spec)
 
 
 def strassen_2d(

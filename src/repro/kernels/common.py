@@ -1,9 +1,9 @@
 """Shared Pallas kernel utilities.
 
-This container is CPU-only: TPU is the compilation TARGET, not the runtime.
-Every kernel accepts ``interpret=`` and defaults to interpret mode when no
-TPU is present, so the same call sites run (slowly, but bit-faithfully at
-the algorithm level) on CPU and compile to Mosaic on a real TPU.
+Every kernel accepts ``interpret=`` and defaults to interpret mode when the
+default backend is not a TPU. On a TPU the same call sites compile to
+Mosaic; on CPU hosts (the test suite) they run through the Pallas
+interpreter, slowly but bit-faithfully at the algorithm level.
 """
 from __future__ import annotations
 
@@ -24,12 +24,16 @@ def cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
-def pick_block(dim: int, preferred: int, align: int = 128) -> int:
+def pick_block(dim: int, preferred: int, align: int = 128, *, interpret: bool) -> int:
     """Largest block <= preferred that divides dim, preferring MXU alignment.
 
     TPU MXU wants the trailing two tile dims in multiples of (8, 128) for
     fp32 and (16, 128) for bf16; ``preferred`` should already be a multiple
-    of 128. For small test shapes we fall back to the dim itself.
+    of 128. A dim no larger than ``preferred`` is one whole block, which
+    Mosaic accepts at any size. A larger dim with no aligned divisor has no
+    legal TPU tiling: compiled kernels raise here (callers pad, as
+    :func:`repro.kernels.strassen.ops.strassen_matmul_fused_padded` does),
+    and only interpret mode falls back to any divisor.
     """
     if dim <= preferred:
         return dim
@@ -38,7 +42,11 @@ def pick_block(dim: int, preferred: int, align: int = 128) -> int:
         if dim % b == 0:
             return b
         b -= align
-    # No aligned divisor — fall back to any divisor (interpret-mode tests).
+    if not interpret:
+        raise ValueError(
+            f"dim {dim} has no block <= {preferred} that is a multiple of "
+            f"{align}; pad it to a multiple of {align} before the kernel"
+        )
     b = preferred
     while b > 1:
         if dim % b == 0:

@@ -132,8 +132,8 @@ def flash_attention_pallas(
     assert hq % hkv == 0, (hq, hkv)
     group = hq // hkv
     scale = scale if scale is not None else d**-0.5
-    bq = pick_block(sq, block_q)
-    bk = pick_block(sk, block_k)
+    bq = pick_block(sq, block_q, interpret=interpret)
+    bk = pick_block(sk, block_k, interpret=interpret)
 
     kernel = functools.partial(_flash_kernel, causal, window, scale, bq, bk)
     return pl.pallas_call(

@@ -62,7 +62,10 @@ def matmul_pallas(
         interpret = default_interpret()
     (m, k), (k2, n) = a.shape, b.shape
     assert k == k2, (a.shape, b.shape)
-    bm, bn, bk = pick_block(m, block_m), pick_block(n, block_n), pick_block(k, block_k)
+    bm, bn, bk = (
+        pick_block(d, blk, interpret=interpret)
+        for d, blk in ((m, block_m), (n, block_n), (k, block_k))
+    )
     out_dtype = out_dtype or a.dtype
     return pl.pallas_call(
         _matmul_kernel,
@@ -113,7 +116,10 @@ def batched_matmul_pallas(
         interpret = default_interpret()
     (mb, m, k), (_, k2, n) = a.shape, b.shape
     assert k == k2 and b.shape[0] == mb, (a.shape, b.shape)
-    bm, bn, bk = pick_block(m, block_m), pick_block(n, block_n), pick_block(k, block_k)
+    bm, bn, bk = (
+        pick_block(d, blk, interpret=interpret)
+        for d, blk in ((m, block_m), (n, block_n), (k, block_k))
+    )
     out_dtype = out_dtype or a.dtype
     return pl.pallas_call(
         _batched_matmul_kernel,

@@ -44,7 +44,7 @@ def rmsnorm_pallas(
         interpret = default_interpret()
     r, d = x.shape
     assert w.shape == (d,), (x.shape, w.shape)
-    br = pick_block(r, block_rows, align=8)
+    br = pick_block(r, block_rows, align=8, interpret=interpret)
     return pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps),
         grid=(r // br,),

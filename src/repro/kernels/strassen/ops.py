@@ -6,7 +6,7 @@ Three pipelines, in increasing distance from the paper:
   combine level materialized, like Stark's shuffles) but with each stage's
   adds fused by the divide/combine kernels and leaves on the MXU kernel.
 * :func:`strassen_matmul_fused`  — the beyond-paper pipeline: unrolled
-  einsum levels down to the last, which runs entirely in-kernel
+  BFS levels down to the last, which runs entirely in-kernel
   (divide + 7 products + combine per tile). Used by backend 'strassen_fused'.
 """
 from __future__ import annotations
@@ -19,11 +19,13 @@ import jax.numpy as jnp
 
 from repro.core.coefficients import get_scheme
 from repro.core.strassen import (
+    accumulation_dtype,
     combine_level,
     divide_level,
     merge_quadrants,
     split_quadrants,
 )
+from repro.kernels.common import cdiv
 from repro.kernels.matmul.matmul import batched_matmul_pallas
 from repro.kernels.strassen.strassen import (
     combine_pallas,
@@ -36,6 +38,11 @@ __all__ = [
     "strassen_matmul_fused",
     "strassen_matmul_fused_padded",
 ]
+
+# The fused leaf's preferred block (strassen1_matmul_pallas's default
+# block_m/n/k) and the lane alignment a larger quadrant must meet.
+_LEAF_BLOCK = 256
+_LEAF_ALIGN = 128
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "scheme_name", "interpret"))
@@ -75,30 +82,47 @@ def strassen_matmul_fused(
 ) -> jax.Array:
     """Fused pipeline: last level runs fully in-kernel (DFS step in VMEM).
 
-    depth-1 outer levels are unrolled einsums (BFS levels, shardable) run
-    at the caller's ``precision``; the final level never materializes its
-    7/4x intermediates and always accumulates in fp32 on the MXU (the
-    kernel's preferred_element_type), which is the strongest precision the
-    leaf offers.
+    depth-1 outer levels are unrolled BFS levels (shardable; exact
+    signed sums, see :func:`repro.core.strassen.divide_level`); the final
+    level never materializes its 7/4x intermediates, runs its products at
+    the caller's ``precision`` and always accumulates in fp32 on the MXU
+    (the kernel's preferred_element_type).
     """
     if depth < 1:
         raise ValueError("fused pipeline needs depth >= 1")
     scheme = get_scheme(scheme_name)
-    a_coef = jnp.asarray(scheme.a_coef)
-    b_coef = jnp.asarray(scheme.b_coef)
-    c_coef = jnp.asarray(scheme.c_coef)
+    a_coef, b_coef, c_coef = scheme.a_coef, scheme.b_coef, scheme.c_coef
 
+    out_dtype = jnp.result_type(a, b)
     ta, tb = a[None], b[None]
     for _ in range(depth - 1):
-        ta = divide_level(ta, a_coef, precision=precision)
-        tb = divide_level(tb, b_coef, precision=precision)
+        ta = divide_level(ta, a_coef)
+        tb = divide_level(tb, b_coef)
+    # With outer levels left to combine, the kernel hands its products over
+    # in the accumulation dtype, rounding once after the last combine.
     cq = strassen1_matmul_pallas(
-        split_quadrants(ta), split_quadrants(tb), scheme=scheme, interpret=interpret
+        split_quadrants(ta), split_quadrants(tb), scheme=scheme,
+        out_dtype=accumulation_dtype(out_dtype) if depth > 1 else out_dtype,
+        precision=precision, interpret=interpret,
     )
     prod = merge_quadrants(cq)
     for _ in range(depth - 1):
-        prod = combine_level(prod, c_coef, precision=precision)
-    return prod[0]
+        prod = combine_level(prod, c_coef)
+    return prod[0].astype(out_dtype)
+
+
+def _padded_dim(d: int, depth: int) -> int:
+    """Smallest size >= d whose depth-level quadrant the leaf can tile.
+
+    The quadrant is ``size / 2**depth``. Up to the leaf's preferred block
+    it is one whole block, legal at any size; above it, it must be a
+    multiple of the 128-lane alignment so an aligned block divides it.
+    """
+    step = 2**depth
+    q = cdiv(d, step)
+    if q > _LEAF_BLOCK:
+        q = cdiv(q, _LEAF_ALIGN) * _LEAF_ALIGN
+    return q * step
 
 
 @functools.partial(
@@ -115,16 +139,15 @@ def strassen_matmul_fused_padded(
 ) -> jax.Array:
     """Fused pipeline for arbitrary (M, K) @ (K, N), odd dims included.
 
-    Zero-pads each dim up to the next multiple of 2**depth, runs
-    :func:`strassen_matmul_fused`, and slices back. Padding rows/columns
-    contribute exactly zero to every M-term (the scheme is bilinear), so
-    the unpadded block of C is exact — the same argument Stark uses for
-    its non-power-of-two Block layout.
+    Zero-pads each dim so the leaf kernel gets a legal TPU tiling (see
+    :func:`_padded_dim`), runs :func:`strassen_matmul_fused`, and slices
+    back. Padding rows/columns contribute exactly zero to every M-term
+    (the scheme is bilinear), so the unpadded block of C is exact — the
+    same argument Stark uses for its non-power-of-two Block layout.
     """
     m, k = a.shape
     n = b.shape[1]
-    step = 2**depth
-    mp, kp, np_ = (-(-d // step) * step for d in (m, k, n))
+    mp, kp, np_ = (_padded_dim(d, depth) for d in (m, k, n))
     if (mp, kp, np_) == (m, k, n):
         return strassen_matmul_fused(
             a, b, depth=depth, scheme_name=scheme_name,

@@ -83,7 +83,8 @@ def divide_pallas(
     m, four, h, w = x.shape
     assert four == 4, x.shape
     r = coef.shape[0]
-    bh, bw = pick_block(h, block), pick_block(w, block)
+    bh = pick_block(h, block, interpret=interpret)
+    bw = pick_block(w, block, interpret=interpret)
     return pl.pallas_call(
         functools.partial(_divide_kernel, np.asarray(coef)),
         grid=(m, h // bh, w // bw),
@@ -114,7 +115,8 @@ def combine_pallas(
         interpret = default_interpret()
     m, r, h, w = products.shape
     assert r == c_coef.shape[1], (products.shape, c_coef.shape)
-    bh, bw = pick_block(h, block), pick_block(w, block)
+    bh = pick_block(h, block, interpret=interpret)
+    bw = pick_block(w, block, interpret=interpret)
     return pl.pallas_call(
         functools.partial(_combine_kernel, np.asarray(c_coef)),
         grid=(m, h // bh, w // bw),
@@ -125,7 +127,7 @@ def combine_pallas(
     )(products)
 
 
-def _strassen1_kernel(scheme: Scheme, aq_ref, bq_ref, o_ref, acc_ref):
+def _strassen1_kernel(scheme: Scheme, precision, aq_ref, bq_ref, o_ref, acc_ref):
     """One (s, i, j, k) grid step of the fused one-level Strassen matmul.
 
     VMEM residency per step: 4 A-quadrant tiles, 4 B-quadrant tiles, the
@@ -143,13 +145,28 @@ def _strassen1_kernel(scheme: Scheme, aq_ref, bq_ref, o_ref, acc_ref):
     for p in range(r):
         left = _signed_sum(a_quads, scheme.a_coef[p])
         right = _signed_sum(b_quads, scheme.b_coef[p])
-        acc_ref[p] += jnp.dot(left, right, preferred_element_type=jnp.float32)
+        acc_ref[p] += jnp.dot(
+            left, right, preferred_element_type=jnp.float32, precision=precision
+        )
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _flush():
         prods = [acc_ref[p] for p in range(r)]
         for k in range(4):
             o_ref[0, k] = _signed_sum(prods, scheme.c_coef[k]).astype(o_ref.dtype)
+
+
+def _leaf_precision(precision: Optional[str]):
+    """Map a jax precision name onto the two that Mosaic's dot supports.
+
+    'default' / 'fastest' / 'bfloat16' (and None) run at Mosaic's default;
+    every stronger request runs at HIGHEST, never below what was asked.
+    """
+    if precision in (None, "default", "fastest", "bfloat16"):
+        return None
+    if precision in ("high", "highest", "tensorfloat32", "float32"):
+        return jax.lax.Precision.HIGHEST
+    raise ValueError(f"unknown matmul precision {precision!r}")
 
 
 def strassen1_matmul_pallas(
@@ -161,6 +178,7 @@ def strassen1_matmul_pallas(
     block_n: int = 256,
     block_k: int = 256,
     out_dtype=None,
+    precision: Optional[str] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fused one-level Strassen on quadrant layout.
@@ -168,6 +186,8 @@ def strassen1_matmul_pallas(
     Args:
       aq: (mb, 4, M2, K2) A-quadrants (batched over mb leaves).
       bq: (mb, 4, K2, N2) B-quadrants.
+      precision: jax precision name for the 7 leaf products (see
+        :func:`_leaf_precision`); None is Mosaic's default.
 
     Returns:
       (mb, 4, M2, N2) C-quadrants.
@@ -179,10 +199,13 @@ def strassen1_matmul_pallas(
     mb, four, m2, k2 = aq.shape
     _, _, _, n2 = bq.shape
     assert four == 4 and bq.shape[:2] == (mb, 4) and bq.shape[2] == k2
-    bm, bn, bk = pick_block(m2, block_m), pick_block(n2, block_n), pick_block(k2, block_k)
+    bm, bn, bk = (
+        pick_block(d, blk, interpret=interpret)
+        for d, blk in ((m2, block_m), (n2, block_n), (k2, block_k))
+    )
     out_dtype = out_dtype or aq.dtype
     return pl.pallas_call(
-        functools.partial(_strassen1_kernel, scheme),
+        functools.partial(_strassen1_kernel, scheme, _leaf_precision(precision)),
         grid=(mb, m2 // bm, n2 // bn, k2 // bk),
         in_specs=[
             pl.BlockSpec((1, 4, bm, bk), lambda s, i, j, kk: (s, 0, i, kk)),

@@ -64,6 +64,9 @@ def main():
     ap.add_argument("--trace-out", default=None,
                     help="write a Chrome/Perfetto trace of the run here")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro import obs
     from repro.blocks.scheduler import min_depth_for_budget, strassen_oot_matmul

@@ -73,6 +73,9 @@ def main():
     ap.add_argument("--trace-out", default=None,
                     help="write a Chrome/Perfetto trace of the run here")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.trace_out:
         from repro import obs
 

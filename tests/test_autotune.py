@@ -237,6 +237,38 @@ def test_fused_enumerates_when_leaf_runs():
     assert all(c.scheme == "strassen" for c in cands if c.kind == "strassen_fused")
 
 
+def test_pallas_leaf_mode_propagates_compile_errors_on_tpu(monkeypatch):
+    """On a TPU a failing probe is a bug to surface: it raises instead of
+    reporting 'none' (which would silently route around the kernel)."""
+    import repro.kernels.strassen.strassen as ks
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(ks, "strassen1_matmul_pallas", broken)
+    monkeypatch.setattr(compat.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        compat.pallas_leaf_mode.__wrapped__()
+    monkeypatch.setattr(compat.jax, "default_backend", lambda: "cpu")
+    assert compat.pallas_leaf_mode.__wrapped__() == "none"
+
+
+def test_cached_calibration_reused_only_on_its_device_kind():
+    """Decisions and calibration are keyed by device_kind (one TPU
+    generation's constants must not price another's candidates)."""
+    dev = jax.devices()[0]
+    cache = TuningCache()
+    cache.calibration = dataclasses.replace(CALIB, device_kind="another chip")
+    assert autotune._cached_calibration(cache, dev) is None
+    cache.calibration = dataclasses.replace(CALIB, device_kind=dev.device_kind)
+    assert autotune._cached_calibration(cache, dev) == cache.calibration
+    own = autotune.Telemetry()
+    autotune.autotune(
+        4096, 4096, 4096, calibration=CALIB, cache=TuningCache(), telemetry=own
+    )
+    assert f"|{dev.device_kind}:1|" in own.events[-1].key
+
+
 def test_fused_not_enumerated_without_pallas(monkeypatch):
     monkeypatch.setattr(compat, "pallas_leaf_mode", lambda: "none")
     cands = enumerate_candidates(4096, 4096, 4096, min_dim=1, max_depth=2)

@@ -54,3 +54,23 @@ def test_matmul_nondivisible_blocks_fall_back():
     a, b = _rand((96, 80), jnp.float32), _rand((80, 112), jnp.float32)
     got = matmul(a, b, block_m=128, block_n=128, block_k=128)
     np.testing.assert_allclose(np.asarray(got), np.asarray(matmul_ref(a, b)), atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize(
+    "dim,interpret,want",
+    [(200, False, 200), (768, False, 256), (640, False, 128), (1000, True, 250)],
+)
+def test_pick_block_alignment(dim, interpret, want):
+    """A dim up to the preferred block is one whole block; a larger one
+    takes the largest 128-aligned divisor, and only interpret mode may
+    fall back to an unaligned divisor."""
+    from repro.kernels.common import pick_block
+
+    assert pick_block(dim, 256, interpret=interpret) == want
+
+
+def test_pick_block_raises_without_aligned_divisor_when_compiled():
+    from repro.kernels.common import pick_block
+
+    with pytest.raises(ValueError, match="multiple of 128"):
+        pick_block(1000, 256, interpret=False)

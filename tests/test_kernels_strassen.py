@@ -109,6 +109,39 @@ def test_fused_padded_noop_on_divisible_shapes():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=0, rtol=0)
 
 
+@pytest.mark.parametrize("dim", [40, 256, 257, 500, 513, 1000, 1536])
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_fused_padding_gives_tileable_quadrants(dim, depth):
+    """The padded size splits into depth-level quadrants the leaf tiles
+    legally on a TPU: one whole block (<= 256) or a multiple of 128."""
+    from repro.kernels.strassen.ops import _padded_dim
+
+    padded = _padded_dim(dim, depth)
+    q, rem = divmod(padded, 2**depth)
+    assert rem == 0 and padded >= dim
+    assert q <= 256 or q % 128 == 0
+    assert padded - dim < 2**depth * 128
+
+
+@pytest.mark.parametrize("pipeline", ["strassen", "fused"])
+@pytest.mark.parametrize("depth", [1, 2])
+def test_bf16_products_round_once(pipeline, depth):
+    """bf16 leaf products and combine levels are held in f32 and rounded
+    once: on small-integer operands every intermediate is exact, so the
+    result is bit-identical to one bf16 matmul with f32 accumulation."""
+    from repro.core.strassen import strassen_matmul
+
+    ints = np.random.default_rng(3).integers(-8, 9, size=(2, 128, 128))
+    a, b = (jnp.asarray(x, jnp.bfloat16) for x in ints)
+    if pipeline == "fused":
+        got = strassen_matmul_fused(a, b, depth=depth)
+    else:
+        got = strassen_matmul(a, b, depth=depth)
+    want = jnp.matmul(a, b, preferred_element_type=jnp.float32).astype(jnp.bfloat16)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
 def test_fused_winograd_scheme():
     a, b = _rand((64, 64)), _rand((64, 64))
     got = strassen_matmul_fused(a, b, depth=1, scheme_name="winograd")

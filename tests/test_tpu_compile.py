@@ -1,0 +1,105 @@
+"""Compile rehearsals of the Strassen kernels for a described TPU v5e chip.
+
+Nothing here runs on a TPU: the TPU compiler that ships with jax compiles
+for a ``v5e:2x2`` topology that is described, not attached, and refuses
+what the chip would refuse (unaligned blocks, VMEM overruns, programs that
+do not fit HBM) — failures interpret mode cannot see. Every kernel is
+forced to ``interpret=False``: the default backend here is the CPU, where
+the kernels would otherwise choose interpret mode.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and under pytest-xdist
+every worker imports this file. The persistent compilation cache is off
+around these compiles (a cache entry for a device that is absent cannot
+be read back).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.coefficients import get_scheme
+from repro.kernels.strassen.ops import (
+    strassen_matmul_fused,
+    strassen_matmul_fused_padded,
+)
+from repro.kernels.strassen.strassen import (
+    combine_pallas,
+    divide_pallas,
+    strassen1_matmul_pallas,
+)
+
+STRASSEN = get_scheme("strassen")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_strassen1_leaf_compiles_at_256_tiles(one_chip, dtype):
+    aq = _spec((2, 4, 512, 512), dtype, one_chip)
+    fn = jax.jit(
+        lambda a, b: strassen1_matmul_pallas(
+            a, b, block_m=256, block_n=256, block_k=256, interpret=False
+        )
+    )
+    _assert_kernel(fn.lower(aq, aq).compile())
+
+
+def test_fused_depth2_8192_bf16_compiles(one_chip):
+    x = _spec((8192, 8192), jnp.bfloat16, one_chip)
+    compiled = strassen_matmul_fused.lower(x, x, depth=2, interpret=False).compile()
+    _assert_kernel(compiled)
+    # A, B, C and the depth-2 intermediates fit the chip's 16 GB of HBM.
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("stage", ["divide", "combine"])
+def test_divide_combine_2048_bf16_compile(one_chip, stage):
+    if stage == "divide":
+        x = _spec((1, 4, 2048, 2048), jnp.bfloat16, one_chip)
+        fn = jax.jit(lambda t: divide_pallas(t, STRASSEN.a_coef, interpret=False))
+    else:
+        x = _spec((1, 7, 2048, 2048), jnp.bfloat16, one_chip)
+        fn = jax.jit(lambda t: combine_pallas(t, STRASSEN.c_coef, interpret=False))
+    _assert_kernel(fn.lower(x).compile())
+
+
+def test_fused_padded_unaligned_1000_compiles(one_chip):
+    """1000 has no 128-aligned divisor: the padded pipeline must hand the
+    leaf aligned quadrants, or Mosaic refuses the block shape."""
+    x = _spec((1000, 1000), jnp.bfloat16, one_chip)
+    compiled = strassen_matmul_fused_padded.lower(
+        x, x, depth=1, interpret=False
+    ).compile()
+    _assert_kernel(compiled)
