@@ -1,0 +1,5 @@
+"""Seconds per in-device product: the window over the products completed in it."""
+
+
+def read(r):
+    return r.window_s / r.count
