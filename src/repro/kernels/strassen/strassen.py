@@ -92,6 +92,7 @@ def divide_pallas(
         out_specs=pl.BlockSpec((1, r, bh, bw), lambda s, i, j: (s, 0, i, j)),
         out_shape=jax.ShapeDtypeStruct((m, r, h, w), x.dtype),
         interpret=interpret,
+        name="strassen_divide",
     )(x)
 
 
@@ -124,6 +125,7 @@ def combine_pallas(
         out_specs=pl.BlockSpec((1, 4, bh, bw), lambda s, i, j: (s, 0, i, j)),
         out_shape=jax.ShapeDtypeStruct((m, 4, h, w), products.dtype),
         interpret=interpret,
+        name="strassen_combine",
     )(products)
 
 
@@ -215,4 +217,5 @@ def strassen1_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((mb, 4, m2, n2), out_dtype),
         scratch_shapes=[pltpu.VMEM((scheme.n_mults, bm, bn), jnp.float32)],
         interpret=interpret,
+        name="strassen_fused_leaf",
     )(aq, bq)

@@ -12,12 +12,14 @@ repro's single substrate for that decomposition:
   stage / dispatch / fetch spans, and the async-pipeline overlap as
   concurrent tracks.
 * :mod:`repro.obs.metrics` — process-local counters, gauges, and
-  fixed-bucket histograms (TTFT / TPOT per request, wave stage / fetch
-  seconds, autotune hit / miss, pool pages in use) with a
-  ``snapshot()`` dict API.
-* :mod:`repro.obs.export` — Chrome / Perfetto ``trace_event`` JSON and
-  JSONL event-log writers, plus optional ``jax.profiler`` passthrough
-  so spans line up with XLA traces on real hardware.
+  fixed-bucket histograms (TTFT / TPOT per request, autotune hit /
+  miss, pool pages in use) with a ``snapshot()`` dict API.
+* :mod:`repro.obs.export` — the Chrome / Perfetto ``trace_event`` JSON
+  writer and its schema check.
+
+Live spans of an enabled tracer are also ``jax.profiler`` annotations,
+so under a running profiler trace they line up with the device ops on
+one clock.
 
 Tracing is **disabled by default**: ``get_tracer().span(...)`` returns
 a shared no-op context manager (zero allocation) until

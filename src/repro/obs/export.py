@@ -1,4 +1,4 @@
-"""Trace exporters: Chrome/Perfetto ``trace_event`` JSON and JSONL.
+"""Trace exporter: Chrome/Perfetto ``trace_event`` JSON.
 
 The Chrome writer emits complete (``ph: "X"``) events with
 microsecond ``ts``/``dur`` rebased to the tracer epoch. Lanes: spans
@@ -25,10 +25,7 @@ __all__ = [
     "trace_events",
     "to_chrome_trace",
     "write_trace",
-    "write_jsonl",
     "validate_trace",
-    "start_jax_trace",
-    "stop_jax_trace",
 ]
 
 PID = 1  # single-process repro: one constant Chrome pid
@@ -109,32 +106,6 @@ def write_trace(
     return path
 
 
-def write_jsonl(path: str, tracer: Optional[Tracer] = None) -> str:
-    """One JSON object per span (append-friendly event log)."""
-    tracer = tracer or get_tracer()
-    with open(path, "w") as f:
-        for sp in tracer.snapshot():
-            if sp.t1 is None:
-                continue
-            f.write(
-                json.dumps(
-                    {
-                        "name": sp.name,
-                        "cat": sp.cat,
-                        "tag": sp.tag,
-                        "track": sp.track,
-                        "t0": sp.t0 - tracer.epoch,
-                        "dur": sp.t1 - sp.t0,
-                        "span_id": sp.span_id,
-                        "parent_id": sp.parent_id,
-                        "attrs": sp.attrs,
-                    }
-                )
-                + "\n"
-            )
-    return path
-
-
 def validate_trace(source: Union[str, Dict[str, Any]]) -> List[str]:
     """Perfetto-loadability check; returns a list of problems (empty =
     valid). ``source`` is a path or an already-loaded trace object."""
@@ -175,32 +146,6 @@ def validate_trace(source: Union[str, Dict[str, Any]]) -> List[str]:
         if ts is not None and not isinstance(ts, (int, float)):
             errors.append(f"event {i}: non-numeric ts {ts!r}")
     return errors
-
-
-# -- jax.profiler passthrough ---------------------------------------------
-
-
-def start_jax_trace(logdir: str) -> bool:
-    """Start an XLA-level ``jax.profiler`` trace alongside obs spans
-    (so device kernels line up with host spans on real hardware).
-    Best-effort: returns False when the profiler is unavailable."""
-    try:
-        import jax
-
-        jax.profiler.start_trace(logdir)
-        return True
-    except Exception:
-        return False
-
-
-def stop_jax_trace() -> bool:
-    try:
-        import jax
-
-        jax.profiler.stop_trace()
-        return True
-    except Exception:
-        return False
 
 
 def main(argv: Optional[List[str]] = None) -> int:

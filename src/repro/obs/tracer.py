@@ -16,11 +16,19 @@ Design constraints, in order:
    code in context managers — overlap between waves then shows up as
    genuinely concurrent tracks, not nested blocks.
 
+4. **One clock with the device.** Every live span an enabled tracer
+   records (``begin``/``end`` or ``span()``) also opens and closes a
+   ``jax.profiler.TraceAnnotation`` under its bare name, so a running
+   ``jax.profiler`` trace shows it on the ``/host:CPU`` plane beside the
+   device ops. Explicit-time spans (``add_span``, ``event``) cannot be
+   backdated into the profiler and stay out of it; a caller may open a
+   span with ``annotate=False`` where its own mark already covers it.
+
 Timestamps are raw ``time.perf_counter()`` seconds; the exporter
 rebases them against :attr:`Tracer.epoch`. ``begin()``/``end()``
 always produce a timed :class:`Span` (callers may need the duration
 even when tracing is off — e.g. the straggler watchdog); the span is
-only *retained* when the tracer is enabled.
+only *retained* (and annotated) when the tracer is enabled.
 """
 from __future__ import annotations
 
@@ -53,6 +61,8 @@ class Span:
     span_id: int = 0
     parent_id: Optional[int] = None
     thread: int = 0
+    # The open profiler annotation of a live span (None once closed).
+    annotation: Any = dataclasses.field(default=None, repr=False, compare=False)
 
     @property
     def duration(self) -> float:
@@ -93,35 +103,35 @@ NULL_SPAN = _NullSpan()
 class _SpanContext:
     """Context manager wrapping begin/end on an enabled tracer."""
 
-    __slots__ = ("_tracer", "_span", "_jax_ctx")
+    __slots__ = ("_tracer", "_span")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self._span = span
-        self._jax_ctx = None
 
     def __enter__(self) -> Span:
-        if self._tracer.jax_annotations:
-            self._jax_ctx = _jax_annotation(self._span.name)
-            if self._jax_ctx is not None:
-                self._jax_ctx.__enter__()
         return self._span
 
     def __exit__(self, *exc: Any) -> bool:
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(*exc)
         self._tracer.end(self._span)
         return False
 
 
-def _jax_annotation(name: str):
-    """Best-effort ``jax.profiler.TraceAnnotation`` (None off-profiler)."""
-    try:
-        from jax.profiler import TraceAnnotation
+def _open_annotation(name: str):
+    """Enter a ``jax.profiler.TraceAnnotation`` named ``name``. With no
+    profiler running it records nothing and costs about half a
+    microsecond."""
+    from jax.profiler import TraceAnnotation
 
-        return TraceAnnotation(name)
-    except Exception:
-        return None
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+def _close_annotation(span: Span) -> None:
+    if span.annotation is not None:
+        span.annotation.__exit__(None, None, None)
+        span.annotation = None
 
 
 class Tracer:
@@ -131,11 +141,9 @@ class Tracer:
         self,
         enabled: bool = False,
         max_spans: int = 200_000,
-        jax_annotations: bool = False,
     ):
         self.enabled = enabled
         self.max_spans = max_spans
-        self.jax_annotations = jax_annotations
         self.epoch = time.perf_counter()
         self.spans: List[Span] = []
         self.dropped = 0
@@ -163,10 +171,12 @@ class Tracer:
         cat: str = "span",
         tag: Optional[str] = None,
         track: Optional[str] = None,
+        annotate: bool = True,
         **attrs: Any,
     ) -> Span:
         """Open a span. Always returns a timed Span (duration is valid
-        even when disabled); it is only retained when enabled."""
+        even when disabled); it is only retained when enabled, and then
+        also opens a profiler annotation unless ``annotate`` is False."""
         sp = Span(
             name=name,
             t0=time.perf_counter(),
@@ -182,11 +192,14 @@ class Tracer:
             if st:
                 sp.parent_id = st[-1].span_id
             st.append(sp)
+            if annotate:
+                sp.annotation = _open_annotation(name)
         return sp
 
     def end(self, span: Optional[Span], **attrs: Any) -> Optional[Span]:
         """Close ``span``. Tolerates exception unwinding: pops the
-        thread stack down through ``span`` if children were left open."""
+        thread stack down through ``span`` if children were left open,
+        closing their profiler annotations first."""
         if span is None or isinstance(span, _NullSpan):
             return None
         span.t1 = time.perf_counter()
@@ -198,6 +211,9 @@ class Tracer:
                 top = st.pop()
                 if top is span:
                     break
+                _close_annotation(top)
+        _close_annotation(span)
+        if self.enabled and span.span_id:
             self._retain(span)
         return span
 
@@ -305,15 +321,12 @@ def get_tracer() -> Tracer:
 def configure(
     enabled: Optional[bool] = None,
     *,
-    jax_annotations: Optional[bool] = None,
     max_spans: Optional[int] = None,
 ) -> Tracer:
     """Reconfigure the global tracer in place (identity is stable so
     modules may cache ``get_tracer()`` safely)."""
     if enabled is not None:
         _GLOBAL.enabled = enabled
-    if jax_annotations is not None:
-        _GLOBAL.jax_annotations = jax_annotations
     if max_spans is not None:
         _GLOBAL.max_spans = max_spans
     return _GLOBAL

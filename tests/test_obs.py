@@ -1,6 +1,8 @@
 """Unified tracing & metrics layer: tracer semantics, histogram math,
 Perfetto export schema, the rewired scheduler/serving telemetry, and the
 disabled-mode overhead guard."""
+import contextlib
+import glob
 import json
 import time
 
@@ -223,17 +225,92 @@ def test_export_cli_roundtrip(tracer, tmp_path):
     assert obs_export.main([good, bad]) == 1
 
 
-def test_write_jsonl(tracer, tmp_path):
-    with tracer.span("a", tag="1"):
-        pass
-    path = str(tmp_path / "spans.jsonl")
-    obs_export.write_jsonl(path, tracer)
-    rows = [json.loads(line) for line in open(path)]
-    assert rows[0]["name"] == "a" and rows[0]["tag"] == "1"
-    assert rows[0]["dur"] >= 0.0
+# -- profiler clock: live spans as jax.profiler annotations ---------------
+
+
+@contextlib.contextmanager
+def _profiled(logdir):
+    """Run the body under a CPU ``jax.profiler`` trace; the list it yields
+    is then filled with the ``/host:CPU`` events as (name, t0_ns, t1_ns)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    events = []
+    jax.profiler.start_trace(str(logdir))
+    try:
+        yield events
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(logdir / "**" / "*.xplane.pb"), recursive=True)
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                events.append((e.name, e.start_ns, e.start_ns + e.duration_ns))
+
+
+def _named(events, name):
+    return [(t0, t1) for n, t0, t1 in events if n == name]
+
+
+def test_live_spans_are_profiler_annotations(tracer, tmp_path):
+    with _profiled(tmp_path) as events:
+        outer = tracer.begin("t.outer", tag="0")
+        with tracer.span("t.mid"):
+            inner = tracer.begin("t.inner", track="lane")
+            tracer.end(inner)
+        now = time.perf_counter()
+        tracer.add_span("t.explicit", now - 0.01, now, parent=outer)
+        tracer.event("t.instant")
+        tracer.end(outer)
+    (o,) = _named(events, "t.outer")  # the bare name: no tag folded in
+    (m,) = _named(events, "t.mid")
+    (i,) = _named(events, "t.inner")
+    assert o[0] <= m[0] <= i[0] <= i[1] <= m[1] <= o[1]
+    assert not _named(events, "t.explicit") and not _named(events, "t.instant")
+    assert all(sp.annotation is None for sp in tracer.snapshot())
+
+
+def test_unwound_children_close_their_annotations(tracer, tmp_path):
+    with _profiled(tmp_path) as events:
+        outer = tracer.begin("t.outer")
+        orphan = tracer.begin("t.orphan")  # left open, as if an exception skipped its end
+        tracer.end(outer)
+    (o,) = _named(events, "t.outer")
+    (c,) = _named(events, "t.orphan")
+    assert o[0] <= c[0] <= c[1] <= o[1]
+    assert orphan.annotation is None
+
+
+def test_disabled_tracer_emits_no_annotation(tmp_path):
+    tr = obs_tracer.Tracer(enabled=False)
+    with _profiled(tmp_path) as events:
+        with tr.span("t.off"):
+            sp = tr.begin("t.off_begin")
+            tr.end(sp)
+    assert sp.annotation is None
+    assert not _named(events, "t.off") and not _named(events, "t.off_begin")
+
+
+def test_unannotated_span_is_recorded_but_not_profiled(tracer, tmp_path):
+    with _profiled(tmp_path) as events:
+        sp = tracer.begin("t.quiet", annotate=False)
+        tracer.end(sp)
+    assert tracer.find("t.quiet") == [sp]
+    assert not _named(events, "t.quiet")
 
 
 # -- scheduler rewire: recursion-tree spans + derived OotStats -------------
+
+
+_OOT_PHASES = (
+    ("ingest_s", "oot.ingest"),
+    ("divide_s", "oot.divide"),
+    ("leaf_s", "oot.leaf_waves"),
+    ("combine_s", "oot.combine"),
+    ("assemble_s", "oot.assemble"),
+)
 
 
 def _oot_traced_run():
@@ -292,8 +369,10 @@ def test_oot_stats_derived_from_spans(global_tracing):
     _, stats = _oot_traced_run()
     root = tr.find("oot.matmul")[0]
     assert stats.total_s == pytest.approx(root.duration)
-    assert stats.divide_s == pytest.approx(tr.find("oot.divide")[0].duration)
-    assert stats.leaf_s == pytest.approx(tr.find("oot.leaf_waves")[0].duration)
+    for field, name in _OOT_PHASES:
+        (span,) = tr.find(name)
+        assert span.parent_id == root.span_id
+        assert getattr(stats, field) == pytest.approx(span.duration)
     assert stats.stage_s == pytest.approx(
         sum(sp.duration for sp in tr.find("wave.stage"))
     )
@@ -324,6 +403,80 @@ def test_overlap_efficiency_parity_with_wave_events():
     for e in ev:
         assert e["issue_start"] <= e["issue_end"] <= e["dispatch_end"]
         assert e["dispatch_end"] <= e["fetch_end"] and e["fetch_start"] <= e["fetch_end"]
+
+
+def _oot_small_problem(budget_slots=12):
+    """256^2 bf16 operands and a depth-2 budget with room for
+    ``budget_slots`` pipelined leaf slots: 12 gives waves of 4, so five
+    waves."""
+    import ml_dtypes
+
+    from repro.blocks.scheduler import pipelined_leaf_bytes
+
+    n = 256
+    rng = np.random.default_rng(1)
+    a = rng.standard_normal((n, n)).astype(ml_dtypes.bfloat16)
+    b = rng.standard_normal((n, n)).astype(ml_dtypes.bfloat16)
+    return a, b, budget_slots * pipelined_leaf_bytes(n, n, n, 2, a.dtype)
+
+
+def _oot_small_run(a, b, budget):
+    from repro.blocks.scheduler import strassen_oot_matmul
+
+    return strassen_oot_matmul(
+        a, b, depth=2, budget_bytes=budget, backend=MatmulBackend(kind="naive")
+    )
+
+
+def test_oot_phases_on_the_profiler_clock(tmp_path):
+    import jax
+
+    problem = _oot_small_problem()
+    _oot_small_run(*problem)  # compile the leaf outside the trace
+    with _profiled(tmp_path) as events:
+        with jax.profiler.TraceAnnotation("test.call"):
+            _, stats = _oot_small_run(*problem)
+    assert stats.waves >= 2
+    (call,) = _named(events, "test.call")
+    phases = []
+    for _, name in _OOT_PHASES:
+        (iv,) = _named(events, name)
+        assert call[0] <= iv[0] <= iv[1] <= call[1], name
+        phases.append(iv)
+    # In order, with no overlap, and together nearly the whole call.
+    assert all(p[1] <= q[0] for p, q in zip(phases, phases[1:]))
+    covered = sum(t1 - t0 for t0, t1 in phases)
+    assert covered >= 0.95 * (call[1] - call[0])
+    # The root span is left to the caller's mark.
+    assert not _named(events, "oot.matmul")
+    assert len(_named(events, "wave.fetch")) == stats.waves
+
+
+def _divide_bytes_closed_form(n, depth, in_item, acc_item, reads=12, rank=7):
+    """Bytes the divide of both square operands reads and writes.
+
+    Level l (1-based) has rank**(l - 1) parents. Each reads ``reads``
+    quadrants (the nonzero coefficients of the plan's rows: 12 for
+    Strassen's A and for its B) and writes ``rank`` children, all of
+    (n / 2**l)**2 entries; level 1 reads the input dtype, every write and
+    every later read is in the accumulation dtype.
+    """
+    total = 0
+    for level in range(1, depth + 1):
+        parent_item = in_item if level == 1 else acc_item
+        quad = (n >> level) ** 2
+        total += rank ** (level - 1) * quad * (reads * parent_item + rank * acc_item)
+    return 2 * total
+
+
+def test_divide_bytes_matches_closed_form():
+    _, stats = _oot_small_run(*_oot_small_problem())
+    assert stats.divide_bytes == _divide_bytes_closed_form(256, 2, 2, 4) == 6_062_080
+    # The benchmark's out-of-core size: 8192^2 bf16, depth 2.
+    assert _divide_bytes_closed_form(8192, 2, 2, 4) == 6_207_569_920
+    phases = sum(getattr(stats, field) for field, _ in _OOT_PHASES)
+    assert stats.total_s >= phases
+    assert all(getattr(stats, field) > 0 for field, _ in _OOT_PHASES)
 
 
 def test_oot_stats_ring_isolation():
