@@ -36,7 +36,9 @@ __all__ = [
     "split_quadrants",
     "merge_quadrants",
     "divide_level",
+    "divide_level_quadrants",
     "combine_level",
+    "combine_level_quadrants",
     "strassen_matmul",
     "leaf_count",
     "accumulation_dtype",
@@ -64,33 +66,60 @@ def leaf_count(scheme: Scheme, depth: int) -> int:
     return scheme.n_mults**depth
 
 
+def _block(x: jax.Array, i: int, j: int, n: int) -> jax.Array:
+    """Block (i, j) of x's two minor dims cut into an n x n grid.
+
+    A static slice: quadrants are addressed in place, never by reshaping a
+    minor dim into (2, half), which on a TPU re-tiles the whole array.
+    """
+    r, c = x.shape[-2] // n, x.shape[-1] // n
+    return x[..., i * r:(i + 1) * r, j * c:(j + 1) * c]
+
+
+def _grid(blocks, n: int) -> jax.Array:
+    """Inverse of :func:`_block`: the row-major n*n blocks as one matrix.
+
+    Each block is written in place into one buffer; a concatenate would
+    first materialize the blocks and then copy them (measured slower on a
+    TPU v5e, and XLA there may pick a transposed layout to feed it).
+    """
+    *lead, h, w = blocks[0].shape
+    out = jnp.zeros((*lead, n * h, n * w), blocks[0].dtype)
+    for k, blk in enumerate(blocks):
+        i, j = divmod(k, n)
+        out = out.at[..., i * h:(i + 1) * h, j * w:(j + 1) * w].set(blk)
+    return out
+
+
+def _sub_block(q: int, s: int) -> tuple[int, int]:
+    """Position of sub-block s of quadrant q in the 4 x 4 grid of a matrix."""
+    return 2 * (q // 2) + s // 2, 2 * (q % 2) + s % 2
+
+
+def _check_even(shape) -> None:
+    if shape[-2] % 2 or shape[-1] % 2:
+        raise ValueError(f"need even dims, got {shape}")
+
+
 def split_quadrants(x: jax.Array) -> jax.Array:
     """(..., r, c) -> (..., 4, r/2, c/2), quadrants row-major [11, 12, 21, 22].
 
     This is the paper's "Divide" of a sub-matrix into four equal quadrants
     (Fig. 3 "index reordering"), vectorized over any leading batch dims.
     """
-    *lead, r, c = x.shape
-    if r % 2 or c % 2:
-        raise ValueError(f"need even dims, got {x.shape}")
-    hr, hc = r // 2, c // 2
-    x = x.reshape(*lead, 2, hr, 2, hc)
-    x = jnp.moveaxis(x, -2, -3)  # (..., 2, 2, hr, hc)
-    return x.reshape(*lead, 4, hr, hc)
+    _check_even(x.shape)
+    return jnp.stack([_block(x, q // 2, q % 2, 2) for q in range(4)], axis=-3)
 
 
 def merge_quadrants(q: jax.Array) -> jax.Array:
     """Inverse of :func:`split_quadrants`: (..., 4, hr, hc) -> (..., 2hr, 2hc)."""
-    *lead, four, hr, hc = q.shape
-    if four != 4:
+    if q.ndim < 3 or q.shape[-3] != 4:
         raise ValueError(f"need (..., 4, hr, hc), got {q.shape}")
-    q = q.reshape(*lead, 2, 2, hr, hc)
-    q = jnp.moveaxis(q, -3, -2)  # (..., 2, hr, 2, hc)
-    return q.reshape(*lead, 2 * hr, 2 * hc)
+    return _grid([q[..., k, :, :] for k in range(4)], 2)
 
 
-def _coef_combination(coef, parts: jax.Array) -> jax.Array:
-    """``einsum('pq,mqij->mpij', coef, parts)`` as elementwise signed sums.
+def _signed_sum(terms, coefs) -> jax.Array:
+    """``sum_q coefs[q] * terms[q]`` as an elementwise signed sum.
 
     The scheme coefficients are constants (0, +-1 for every registered
     scheme), so each output block is a fused sum of input blocks in the
@@ -98,21 +127,16 @@ def _coef_combination(coef, parts: jax.Array) -> jax.Array:
     f32 operands to bf16, putting bf16 error into f32 products (and into
     the f32 accumulation of bf16 ones) whatever precision the leaves use.
     """
-    coef = np.asarray(coef)
-    rows = []
-    for row in coef:
-        acc = None
-        for q, c in enumerate(row.tolist()):
-            if c == 0.0:
-                continue
-            term = parts[:, q]
-            if c == -1.0:
-                term = -term
-            elif c != 1.0:
-                term = term * jnp.asarray(c, parts.dtype)
-            acc = term if acc is None else acc + term
-        rows.append(acc if acc is not None else jnp.zeros_like(parts[:, 0]))
-    return jnp.stack(rows, axis=1)
+    acc = None
+    for term, c in zip(terms, np.asarray(coefs).tolist()):
+        if c == 0.0:
+            continue
+        if c == -1.0:
+            term = -term
+        elif c != 1.0:
+            term = term * jnp.asarray(c, term.dtype)
+        acc = term if acc is None else acc + term
+    return acc if acc is not None else jnp.zeros_like(terms[0])
 
 
 def divide_level(x: jax.Array, coef: np.ndarray) -> jax.Array:
@@ -126,9 +150,28 @@ def divide_level(x: jax.Array, coef: np.ndarray) -> jax.Array:
     of the final leaf index reproduce the paper's M-index tag path.
     """
     m, r, c = x.shape
-    q = split_quadrants(x)  # (m, 4, r/2, c/2)
-    out = _coef_combination(coef, q)
+    _check_even(x.shape)
+    quads = [_block(x, q // 2, q % 2, 2) for q in range(4)]
+    out = jnp.stack([_signed_sum(quads, row) for row in coef], axis=1)
     return out.reshape(m * out.shape[1], r // 2, c // 2)
+
+
+def divide_level_quadrants(x: jax.Array, coef: np.ndarray) -> jax.Array:
+    """:func:`divide_level` then :func:`split_quadrants`, in one pass.
+
+    (m, r, c) or (r, c) -> (m*rank, 4, r/4, c/4): entry [p, s] is the
+    signed sum over quadrants q of sub-block s of quadrant q, each a static
+    slice of x. This is the fused leaf's operand layout, written directly.
+    """
+    *lead, r, c = x.shape
+    if r % 4 or c % 4:
+        raise ValueError(f"need dims divisible by 4, got {x.shape}")
+    out = jnp.zeros((*lead, len(coef), 4, r // 4, c // 4), x.dtype)
+    for p, row in enumerate(coef):
+        for s in range(4):
+            sub = [_block(x, *_sub_block(q, s), 4) for q in range(4)]
+            out = out.at[..., p, s, :, :].set(_signed_sum(sub, row))
+    return out.reshape(-1, 4, r // 4, c // 4)
 
 
 def combine_level(products: jax.Array, c_coef: np.ndarray) -> jax.Array:
@@ -142,10 +185,30 @@ def combine_level(products: jax.Array, c_coef: np.ndarray) -> jax.Array:
     mr, hr, hc = products.shape
     if mr % rank:
         raise ValueError(f"batch {mr} not divisible by rank {rank}")
-    m = mr // rank
-    prod = products.reshape(m, rank, hr, hc)
-    quads = _coef_combination(c_coef, prod)
-    return merge_quadrants(quads)
+    prod = products.reshape(mr // rank, rank, hr, hc)
+    parts = [prod[:, p] for p in range(rank)]
+    return _grid([_signed_sum(parts, row) for row in c_coef], 2)
+
+
+def combine_level_quadrants(cq: jax.Array, c_coef: np.ndarray, dtype) -> jax.Array:
+    """:func:`merge_quadrants` then :func:`combine_level`, in one pass.
+
+    (m*rank, 4, h, w) -> (m, 4h, 4w) in ``dtype``: the fused leaf's output
+    read as ``cq[:, p, s]``; block s of C-quadrant k is the signed sum over
+    p, cast to ``dtype`` in the same expression.
+    """
+    rank = c_coef.shape[1]
+    mr, four, h, w = cq.shape
+    if mr % rank or four != 4:
+        raise ValueError(f"need (m*{rank}, 4, h, w), got {cq.shape}")
+    cq = cq.reshape(mr // rank, rank, 4, h, w)
+    blocks = [None] * 16
+    for k, row in enumerate(c_coef):
+        for s in range(4):
+            i, j = _sub_block(k, s)
+            parts = [cq[:, p, s] for p in range(rank)]
+            blocks[4 * i + j] = _signed_sum(parts, row).astype(dtype)
+    return _grid(blocks, 4)
 
 
 def _default_leaf(a: jax.Array, b: jax.Array, *, precision=None) -> jax.Array:
@@ -251,34 +314,13 @@ def strassen_recursive(
     n = b.shape[1]
     if min(m, k, n) <= threshold or m % 2 or k % 2 or n % 2:
         return a @ b
-    aq = split_quadrants(a)  # (4, m/2, k/2)
-    bq = split_quadrants(b)
-    prods = []
-    for p in range(scheme.n_mults):
-        left = _combo(aq, scheme.a_coef[p], a.dtype)
-        right = _combo(bq, scheme.b_coef[p], b.dtype)
-        prods.append(strassen_recursive(left, right, threshold=threshold, scheme=scheme))
-    quads = []
-    for kk in range(4):
-        acc = None
-        for p in range(scheme.n_mults):
-            c = scheme.c_coef[kk, p]
-            if c == 0:
-                continue
-            term = prods[p] if c == 1 else (-prods[p] if c == -1 else c * prods[p])
-            acc = term if acc is None else acc + term
-        quads.append(acc)
-    return merge_quadrants(jnp.stack(quads))
-
-
-def _combo(quads: jax.Array, coef_row: np.ndarray, dtype) -> jax.Array:
-    """Signed sum of quadrants per one coefficient row (serial-form helper)."""
-    acc = None
-    for q in range(4):
-        c = float(coef_row[q])
-        if c == 0.0:
-            continue
-        term = quads[q] if c == 1.0 else (-quads[q] if c == -1.0 else c * quads[q])
-        acc = term if acc is None else acc + term
-    assert acc is not None
-    return acc.astype(dtype)
+    aq = [_block(a, q // 2, q % 2, 2) for q in range(4)]
+    bq = [_block(b, q // 2, q % 2, 2) for q in range(4)]
+    prods = [
+        strassen_recursive(
+            _signed_sum(aq, scheme.a_coef[p]), _signed_sum(bq, scheme.b_coef[p]),
+            threshold=threshold, scheme=scheme,
+        )
+        for p in range(scheme.n_mults)
+    ]
+    return _grid([_signed_sum(prods, row) for row in scheme.c_coef], 2)

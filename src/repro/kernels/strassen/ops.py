@@ -21,7 +21,9 @@ from repro.core.coefficients import get_scheme
 from repro.core.strassen import (
     accumulation_dtype,
     combine_level,
+    combine_level_quadrants,
     divide_level,
+    divide_level_quadrants,
     merge_quadrants,
     split_quadrants,
 )
@@ -94,19 +96,33 @@ def strassen_matmul_fused(
     a_coef, b_coef, c_coef = scheme.a_coef, scheme.b_coef, scheme.c_coef
 
     out_dtype = jnp.result_type(a, b)
-    ta, tb = a[None], b[None]
-    for _ in range(depth - 1):
+    leaf = functools.partial(
+        strassen1_matmul_pallas, scheme=scheme, precision=precision,
+        interpret=interpret,
+    )
+    if depth == 1:
+        cq = leaf(split_quadrants(a)[None], split_quadrants(b)[None], out_dtype=out_dtype)
+        return merge_quadrants(cq[0])
+
+    # Every outer level addresses quadrants by static slices; the last
+    # divide writes the leaf's (m*7, 4, h, w) operands directly and the
+    # first combine reads its output in place, so no level relayouts.
+    # At depth 2 the divide reads A and B as they are: through a unit batch
+    # axis, XLA on a TPU lays the leaf operands out in another order and
+    # then copies them.
+    ta, tb = (a, b) if depth == 2 else (a[None], b[None])
+    for _ in range(depth - 2):
         ta = divide_level(ta, a_coef)
         tb = divide_level(tb, b_coef)
-    # With outer levels left to combine, the kernel hands its products over
-    # in the accumulation dtype, rounding once after the last combine.
-    cq = strassen1_matmul_pallas(
-        split_quadrants(ta), split_quadrants(tb), scheme=scheme,
-        out_dtype=accumulation_dtype(out_dtype) if depth > 1 else out_dtype,
-        precision=precision, interpret=interpret,
+    # The kernel hands its products over in the accumulation dtype,
+    # rounding once after the last combine.
+    acc_dtype = accumulation_dtype(out_dtype)
+    cq = leaf(
+        divide_level_quadrants(ta, a_coef), divide_level_quadrants(tb, b_coef),
+        out_dtype=acc_dtype,
     )
-    prod = merge_quadrants(cq)
-    for _ in range(depth - 1):
+    prod = combine_level_quadrants(cq, c_coef, out_dtype if depth == 2 else acc_dtype)
+    for _ in range(depth - 2):
         prod = combine_level(prod, c_coef)
     return prod[0].astype(out_dtype)
 

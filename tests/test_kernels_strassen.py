@@ -153,3 +153,35 @@ def test_fused_winograd_scheme():
 def test_quadrant_roundtrip_kernel_layout():
     x = _rand((3, 64, 48))
     assert np.allclose(np.asarray(merge_quadrants(split_quadrants(x))), np.asarray(x))
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+@pytest.mark.parametrize("m,k,n", [(128, 128, 128), (64, 128, 192)])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("scheme_name", ["strassen", "winograd"])
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_fused_bits_match_relayout_formulation(depth, scheme_name, dtype, m, k, n):
+    """Slice-addressed outer levels give the bits of the reshape/transpose
+    formulation: every element keeps its terms, their order and dtypes."""
+    import _relayout_ref as ref
+
+    a, b = _rand((m, k), dtype), _rand((k, n), dtype)
+    got = strassen_matmul_fused(a, b, depth=depth, scheme_name=scheme_name)
+    want = ref.strassen_matmul_fused(a, b, depth=depth, scheme_name=scheme_name)
+    assert got.dtype == want.dtype == dtype
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_fused_padded_bits_match_relayout_formulation(depth, dtype):
+    import _relayout_ref as ref
+
+    a, b = _rand((100, 60), dtype), _rand((60, 36), dtype)
+    got = strassen_matmul_fused_padded(a, b, depth=depth)
+    want = ref.strassen_matmul_fused_padded(a, b, depth=depth)
+    np.testing.assert_array_equal(_bits(got), _bits(want))

@@ -12,6 +12,7 @@ from repro.core import (
     STRASSEN,
     WINOGRAD,
     MatmulBackend,
+    combine_level,
     divide_level,
     leaf_count,
     matmul,
@@ -21,6 +22,7 @@ from repro.core import (
     strassen_recursive,
 )
 from repro.core.coefficients import leaf_index_from_path, leaf_tag_path
+from repro.core.strassen import combine_level_quadrants, divide_level_quadrants
 from repro.core.cost_model import (
     CostModel,
     marlin_stages,
@@ -120,6 +122,50 @@ def test_divide_level_ordering_matches_tags():
         q2 = split_quadrants(step1)
         want = jnp.einsum("q,qij->ij", coef[j].astype(a.dtype), q2)
         np.testing.assert_allclose(np.asarray(lvl2[idx]), np.asarray(want), atol=1e-5)
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("scheme", [STRASSEN, WINOGRAD, NAIVE8])
+def test_levels_bits_match_relayout_formulation(scheme, dtype):
+    """Slice-addressed levels at batch m > 1 give the bits of the
+    reshape/transpose formulation, alone and fused with the quadrant split
+    (divide) or merge (combine) of the Pallas leaf's layout."""
+    import _relayout_ref as ref
+
+    rank = scheme.n_mults
+    x = _rand((3, 32, 48)).astype(dtype)
+    prods = _rand((3 * rank, 16, 24))
+    leaf = _rand((3 * rank, 4, 8, 12))
+    pairs = [
+        (split_quadrants(x), ref.split_quadrants(x)),
+        (merge_quadrants(leaf), ref.merge_quadrants(leaf)),
+        (divide_level(x, scheme.a_coef), ref.divide_level(x, scheme.a_coef)),
+        (combine_level(prods, scheme.c_coef), ref.combine_level(prods, scheme.c_coef)),
+        (divide_level_quadrants(x, scheme.b_coef),
+         ref.split_quadrants(ref.divide_level(x, scheme.b_coef))),
+        (combine_level_quadrants(leaf, scheme.c_coef, dtype),
+         ref.combine_level(ref.merge_quadrants(leaf), scheme.c_coef).astype(dtype)),
+    ]
+    for got, want in pairs:
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("scheme", ["strassen", "winograd", "naive8"])
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_strassen_matmul_bits_match_relayout_formulation(depth, scheme, dtype):
+    import _relayout_ref as ref
+
+    a, b = _rand((64, 128)).astype(dtype), _rand((128, 96)).astype(dtype)
+    got = strassen_matmul(a, b, depth=depth, scheme=scheme)
+    want = ref.strassen_matmul(a, b, depth=depth, scheme=scheme)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
 
 
 # ---------------------------------------------------------------- backend

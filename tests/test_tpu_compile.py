@@ -14,6 +14,7 @@ around these compiles (a cache entry for a device that is absent cannot
 be read back).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.coefficients import get_scheme
+from repro.core.compat import compiled_cost_analysis
+from repro.core.strassen import strassen_matmul
 from repro.kernels.strassen.ops import (
     strassen_matmul_fused,
     strassen_matmul_fused_padded,
@@ -76,11 +79,44 @@ def test_strassen1_leaf_compiles_at_256_tiles(one_chip, dtype):
     _assert_kernel(fn.lower(aq, aq).compile())
 
 
+def _entry_opcodes(compiled):
+    """Opcode of each instruction in the compiled program's entry computation."""
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[1:entry.index("\n}")].splitlines()[1:]
+    found = (re.search(r" = .*?[\]})] ([a-z][a-z0-9-]*)\(", line) for line in entry)
+    return [m.group(1) for m in found if m]
+
+
+# Instructions that only move data into another layout. The async
+# copy-start/copy-done pairs (memory-space prefetches) are other opcodes.
+_RELAYOUT = {"copy", "transpose", "reshape"}
+
+
 def test_fused_depth2_8192_bf16_compiles(one_chip):
     x = _spec((8192, 8192), jnp.bfloat16, one_chip)
     compiled = strassen_matmul_fused.lower(x, x, depth=2, interpret=False).compile()
     _assert_kernel(compiled)
+    # The outer divide writes the leaf's quadrant layout and the combine
+    # reads it in place: no relayout between the levels and the kernel,
+    # and far fewer bytes than the 8.13 GiB the reshaped quadrants moved.
+    assert not _RELAYOUT & set(_entry_opcodes(compiled))
+    assert compiled_cost_analysis(compiled)["bytes accessed"] <= 4.5 * 2**30
     # A, B, C and the depth-2 intermediates fit the chip's 16 GB of HBM.
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+def test_strassen_depth2_8192_bf16_compiles(one_chip):
+    """The all-XLA pipeline addresses quadrants by slices too. What relayout
+    is left is XLA's: two copies of the stacked level-2 operands into the
+    batch-major layout the dot reads (13.7 GiB moved with seven copies and
+    seventeen reshapes when quadrants were reshaped)."""
+    x = _spec((8192, 8192), jnp.bfloat16, one_chip)
+    compiled = jax.jit(lambda a, b: strassen_matmul(a, b, depth=2)).lower(x, x).compile()
+    ops = _entry_opcodes(compiled)
+    assert not {"transpose", "reshape"} & set(ops)
+    assert ops.count("copy") <= 2
+    assert compiled_cost_analysis(compiled)["bytes accessed"] <= 11 * 2**30
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
 
 
