@@ -59,6 +59,11 @@ VALID_KINDS: Tuple[str, ...] = (
     "auto",
 )
 
+# The one kind that can spread a product over a MatmulBackend.mesh, and the
+# registered strategy (core/distributed.py) it runs there.
+MESH_KIND = "strassen_fused"
+MESH_STRATEGY = "strassen_fused_sharded"
+
 # Kinds that cannot trace under jit (host-resident pipelines). Jitted
 # surfaces (train/serve/dryrun CLIs) derive their --backend menus as
 # VALID_KINDS minus these.
@@ -209,6 +214,12 @@ class MatmulBackend:
         strassen_oot candidate family). None: 'strassen_oot' budgets one
         single-leaf pipelined wave slot (two leaf working sets plus one
         wave of operand prefetch); 'auto' never picks out-of-core.
+      mesh: a device mesh with a 'data' and/or 'model' axis to spread one
+        product over ('strassen_fused' only): A and C are split into row
+        stripes over those axes, B is gathered to every device, and each
+        device runs the fused pipeline on its stripe
+        (:func:`repro.core.distributed.strassen_fused_sharded`). None:
+        the product runs where its operands are.
     """
 
     kind: str = "naive"
@@ -220,12 +231,18 @@ class MatmulBackend:
     schemes: Tuple[str, ...] = ("strassen", "winograd")
     device_budget: Optional[int] = None
     latency_hiding: bool = False
+    mesh: Optional[jax.sharding.Mesh] = None
 
     def __post_init__(self):
         if self.kind not in VALID_KINDS:
             raise ValueError(
                 f"unknown matmul backend kind {self.kind!r}; "
                 f"valid kinds: {', '.join(VALID_KINDS)}"
+            )
+        if self.mesh is not None and self.kind != MESH_KIND:
+            raise ValueError(
+                f"backend kind {self.kind!r} cannot take a mesh; only "
+                f"{MESH_KIND!r} can"
             )
 
     def configure(self) -> "MatmulBackend":
@@ -419,12 +436,50 @@ def matmul(
     # trace time included. When tracing, eager calls get true wall time;
     # under jit the span covers trace/lowering work (attr traced=True) and
     # the XLA-side timeline comes from the jax.profiler passthrough.
+    mesh = backend.mesh
     with obs_tracer.get_tracer().span(
         "backend.matmul", cat="matmul", m=m, k=k, n=n,
         kind=backend.kind, site=site,
         traced=isinstance(x, jax.core.Tracer),
+        mesh=None if mesh is None else "x".join(map(str, mesh.devices.shape)),
+        strategy=None if mesh is None else MESH_STRATEGY,
     ):
+        if mesh is not None:
+            return _matmul_mesh(x, w, backend, w_logical, lead, m, k, n)
         return _matmul_routed(x, w, backend, w_logical, site, lead, m, k, n)
+
+
+def _matmul_mesh(x, w, backend: MatmulBackend, w_logical, lead, m: int, k: int, n: int):
+    """One product spread over ``backend.mesh`` by :data:`MESH_STRATEGY`.
+
+    The depth is the backend's effective depth for one device's row stripe
+    (M over the row shards, rounded up: the strategy pads M to the stripe
+    grain). C comes back row-sharded over the mesh.
+    """
+    from repro.core import distributed
+
+    if w_logical is not None:
+        raise ValueError(
+            "a MatmulBackend with a mesh lays out its own operands (row "
+            "stripes of A and C, B gathered); w_logical applies only to "
+            "mesh=None"
+        )
+    _, rows = distributed.row_shards(backend.mesh)
+    stripe = -(-m // rows)
+    depth = backend.effective_depth(stripe, k, n)
+    if depth == 0:
+        raise ValueError(
+            f"kind={backend.kind!r} over {rows} row shards needs a per-device "
+            f"stripe ({stripe}, {k}) @ ({k}, {n}) that takes a Strassen level "
+            f"(even dims, all >= min_dim={backend.min_dim}); use mesh=None "
+            f"for smaller products"
+        )
+    strategy = distributed.get_strategy(MESH_STRATEGY)
+    out = strategy(
+        x.reshape(m, k), w, mesh=backend.mesh, depth=depth,
+        scheme=backend.scheme_name, precision=resolve_precision(backend),
+    )
+    return out.reshape(*lead, n)
 
 
 def _matmul_routed(x, w, backend, w_logical, site, lead, m, k, n):

@@ -41,6 +41,7 @@ __all__ = [
     "strassen_2d",
     "strassen_shardmap",
     "strassen_fused_sharded",
+    "row_shards",
     "MESH_STRATEGIES",
     "register_strategy",
     "get_strategy",
@@ -343,6 +344,18 @@ def strassen_shardmap(
     return fn(a, b)
 
 
+def row_shards(mesh: Mesh, rows_axes: Sequence[str] = ("data", "model")):
+    """The axes of ``rows_axes`` present in ``mesh``, and how many row
+    stripes they split a matrix into."""
+    axes = tuple(ax for ax in rows_axes if ax in mesh.shape)
+    if not axes:
+        raise ValueError(f"none of {tuple(rows_axes)} in mesh axes {tuple(mesh.shape)}")
+    n_rows = 1
+    for ax in axes:
+        n_rows *= mesh.shape[ax]
+    return axes, n_rows
+
+
 def strassen_fused_sharded(
     a: jax.Array,
     b: jax.Array,
@@ -371,44 +384,23 @@ def strassen_fused_sharded(
     (row shards * 2**depth) and sliced back, so any shape the autotuner
     enumerates (dims divisible by 2**depth) executes.
 
-    On CPU hosts :func:`repro.core.compat.pallas_leaf_mode` reports
-    'interpret' and the kernel runs in interpret mode (bit-faithful, slow);
-    if pallas is unavailable entirely the body falls back to the jnp
-    reference pipeline, so the strategy stays callable everywhere.
+    On a TPU the kernel compiles; elsewhere it runs in interpret mode
+    (bit-faithful, slow). Where Pallas cannot run at all the call fails,
+    as the local fused path does.
     """
-    from repro.core.compat import pallas_leaf_mode
+    from repro.kernels.strassen.ops import strassen_matmul_fused_padded
 
     if isinstance(scheme, str):
         scheme = get_scheme(scheme)
-    mode = pallas_leaf_mode()
-    axes = tuple(ax for ax in rows_axes if ax in mesh.shape)
-    if not axes:
-        raise ValueError(f"none of {rows_axes} in mesh axes {tuple(mesh.shape)}")
-    n_rows = 1
-    for ax in axes:
-        n_rows *= mesh.shape[ax]
+    axes, n_rows = row_shards(mesh, rows_axes)
     m = a.shape[0]
     grain = n_rows * 2**depth
     mp = -(-m // grain) * grain
     a_p = jnp.pad(a, ((0, mp - m), (0, 0))) if mp != m else a
 
     def body(a_loc, b_rep):
-        if mode == "none":
-            return _s.strassen_matmul(
-                a_loc, b_rep, depth=depth, scheme=scheme, precision=precision
-            )
-        # Imported here, not at function entry: pulling in the ops module
-        # imports pallas, which is exactly what mode == 'none' says this
-        # host cannot do — the jnp fallback above must stay reachable.
-        from repro.kernels.strassen.ops import strassen_matmul_fused_padded
-
         return strassen_matmul_fused_padded(
-            a_loc,
-            b_rep,
-            depth=depth,
-            scheme_name=scheme.name,
-            interpret=(mode != "compiled"),
-            precision=precision,
+            a_loc, b_rep, depth=depth, scheme_name=scheme.name, precision=precision
         )
 
     fn = _shard_map(
@@ -462,8 +454,7 @@ def _req_shardmap_3d(mesh: Mesh, scheme: Scheme) -> bool:
 
 def _req_fused_sharded(mesh: Mesh, scheme: Scheme) -> bool:
     # Enumerable only where the Pallas leaf actually runs (compiled on TPU,
-    # interpret elsewhere); the 'none' fallback inside the strategy is for
-    # direct callers, not the autotuner.
+    # interpret elsewhere).
     from repro.core.compat import pallas_leaf_mode
 
     return "data" in mesh.shape and pallas_leaf_mode() != "none"

@@ -18,9 +18,11 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from repro.core import backend
 from repro.core.coefficients import get_scheme
 from repro.core.compat import compiled_cost_analysis
 from repro.core.strassen import strassen_matmul
@@ -38,7 +40,7 @@ STRASSEN = get_scheme("strassen")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -54,10 +56,21 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(v5e_2x2):
+    """The host's four chips as the (data, model) mesh of the mesh cell."""
+    return Mesh(np.asarray(v5e_2x2.devices).reshape(2, 2), ("data", "model"))
 
 
 def _spec(shape, dtype, sharding):
@@ -139,3 +152,38 @@ def test_fused_padded_unaligned_1000_compiles(one_chip):
         x, x, depth=1, interpret=False
     ).compile()
     _assert_kernel(compiled)
+
+
+@pytest.fixture
+def compiled_leaf(monkeypatch):
+    """The backend's routes pick the kernel's mode from the default backend
+    (the CPU here): make them compile it for the chip."""
+    import repro.kernels.strassen.strassen as kernel
+
+    monkeypatch.setattr(kernel, "default_interpret", lambda: False)
+
+
+def _fused_d2(mesh=None):
+    be = backend.MatmulBackend(kind="strassen_fused", depth=2, mesh=mesh)
+    return lambda a, b: backend.matmul(a, b, be)
+
+
+def test_mesh_route_32768_bf16_fits_each_of_four_chips(four_chips, compiled_leaf):
+    """The mesh cell's program: row-sharded 32768^2 bf16 operands, B
+    gathered, the fused depth-2 pipeline on each chip's 8192-row stripe.
+    Its arguments, output and temporaries fit each chip's 16 GB."""
+    rows = NamedSharding(four_chips, P(("data", "model"), None))
+    x = _spec((32768, 32768), jnp.bfloat16, rows)
+    compiled = jax.jit(_fused_d2(four_chips), out_shardings=rows).lower(x, x).compile()
+    _assert_kernel(compiled)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == 2 * 8192 * 32768 * 2
+    per_chip = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    assert per_chip < 16e9
+
+
+def test_local_route_32768_bf16_does_not_fit_one_chip(one_chip, compiled_leaf):
+    """Why the mesh cell is 32768^2: one chip cannot hold that program."""
+    x = _spec((32768, 32768), jnp.bfloat16, one_chip)
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+        jax.jit(_fused_d2()).lower(x, x).compile()
