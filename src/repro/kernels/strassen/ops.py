@@ -30,6 +30,7 @@ from repro.core.strassen import (
 from repro.kernels.common import cdiv
 from repro.kernels.matmul.matmul import batched_matmul_pallas
 from repro.kernels.strassen.strassen import (
+    LEAF_BLOCK,
     combine_pallas,
     divide_pallas,
     strassen1_matmul_pallas,
@@ -41,9 +42,7 @@ __all__ = [
     "strassen_matmul_fused_padded",
 ]
 
-# The fused leaf's preferred block (strassen1_matmul_pallas's default
-# block_m/n/k) and the lane alignment a larger quadrant must meet.
-_LEAF_BLOCK = 256
+# The lane alignment a quadrant larger than the leaf's narrow block must meet.
 _LEAF_ALIGN = 128
 
 
@@ -130,13 +129,13 @@ def strassen_matmul_fused(
 def _padded_dim(d: int, depth: int) -> int:
     """Smallest size >= d whose depth-level quadrant the leaf can tile.
 
-    The quadrant is ``size / 2**depth``. Up to the leaf's preferred block
+    The quadrant is ``size / 2**depth``. Up to the leaf's narrow block
     it is one whole block, legal at any size; above it, it must be a
     multiple of the 128-lane alignment so an aligned block divides it.
     """
     step = 2**depth
     q = cdiv(d, step)
-    if q > _LEAF_BLOCK:
+    if q > LEAF_BLOCK:
         q = cdiv(q, _LEAF_ALIGN) * _LEAF_ALIGN
     return q * step
 

@@ -1,10 +1,13 @@
 """Fused Strassen kernels vs oracles: divide/combine/fused-matmul sweeps."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.coefficients import get_scheme
 from repro.core.strassen import merge_quadrants, split_quadrants
+from repro.kernels.common import pick_block
+from repro.kernels.strassen import strassen as K
 from repro.kernels.strassen.ops import (
     strassen_matmul_fused,
     strassen_matmul_fused_padded,
@@ -21,6 +24,7 @@ from repro.kernels.strassen.strassen import (
     divide_pallas,
     strassen1_matmul_pallas,
 )
+from repro.obs.metrics import get_metrics
 
 RNG = np.random.default_rng(1)
 TOL = {jnp.float32: 5e-4, jnp.bfloat16: 5e-1}
@@ -185,3 +189,85 @@ def test_fused_padded_bits_match_relayout_formulation(depth, dtype):
     got = strassen_matmul_fused_padded(a, b, depth=depth)
     want = ref.strassen_matmul_fused_padded(a, b, depth=depth)
     np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+def _narrow_blocks(m2, k2, n2):
+    return tuple(pick_block(d, 256, interpret=False) for d in (m2, n2, k2))
+
+
+@pytest.mark.parametrize(
+    "m2,k2,n2,in_dtype,out_dtype,wide",
+    [
+        (4096, 4096, 4096, jnp.bfloat16, jnp.float32, True),  # dense cell, d2
+        (2048, 8192, 8192, jnp.bfloat16, jnp.float32, True),  # mesh cell's stripe, d2
+        (4096, 4096, 4096, jnp.float32, jnp.float32, True),
+        (4096, 4096, 4096, jnp.bfloat16, jnp.bfloat16, True),  # d1 of 8192^2
+        (1024, 128, 512, jnp.bfloat16, jnp.bfloat16, True),
+        (512, 4096, 4096, jnp.bfloat16, jnp.float32, False),
+        (768, 768, 768, jnp.bfloat16, jnp.bfloat16, False),  # d1 of 1536^2
+        (640, 4096, 640, jnp.bfloat16, jnp.float32, False),
+        (4096, 4096, 768, jnp.bfloat16, jnp.float32, False),
+        (16, 1536, 4096, jnp.bfloat16, jnp.float32, False),  # skinny projection
+        (384, 384, 384, jnp.bfloat16, jnp.float32, False),
+        (256, 256, 256, jnp.float32, jnp.float32, False),
+        (64, 64, 64, jnp.bfloat16, jnp.bfloat16, False),
+    ],
+)
+def test_leaf_blocks_from_quadrant_shape(m2, k2, n2, in_dtype, out_dtype, wide):
+    """1024x512 output tiles where m2 and n2 allow them, the 256 ladder
+    elsewhere; every tiling's VMEM estimate lies under the limit it is
+    compiled with (Mosaic's 16 MiB default where none is passed), and the
+    narrow ones pass none, so they compile as they always did."""
+    blocks = K._leaf_blocks(m2, k2, n2, interpret=False)
+    narrow = _narrow_blocks(m2, k2, n2)
+    assert blocks == ((1024, 512, narrow[2]) if wide else narrow)
+    for n_mults in (7, 8):  # Strassen/Winograd, naive8
+        need = K._leaf_vmem_bytes(
+            *blocks, jnp.dtype(in_dtype).itemsize, jnp.dtype(out_dtype).itemsize,
+            n_mults,
+        )
+        limit = K._leaf_vmem_limit(need)
+        assert (limit is not None) == wide
+        assert need < (limit or 16 << 20) <= 96 << 20
+
+
+@pytest.mark.parametrize("dtype,rel_fro", [(jnp.float32, 1e-5), (jnp.bfloat16, 1e-2)])
+def test_strassen1_kernel_wide_blocks_vs_ref(dtype, rel_fro):
+    """The 1024x512 tiling against the f32 oracle. Over k2 = 1024 the
+    kernel's bf16 signed sums stray further per entry than TOL allows, so
+    the bound is on the relative Frobenius error (about 4.5e-3 for one
+    bf16 Strassen level)."""
+    aq = _rand((1, 4, 1024, 1024), dtype)
+    bq = _rand((1, 4, 1024, 1024), dtype)
+    got = np.asarray(strassen1_matmul_pallas(aq, bq, out_dtype=jnp.float32))
+    want = np.asarray(strassen1_matmul_ref(aq, bq), np.float32)
+    assert np.linalg.norm(got - want) / np.linalg.norm(want) < rel_fro
+
+
+def test_strassen1_wide_blocks_bits_match_narrow():
+    """On whole-number operands every sum is exact: the 1024x512 tiling
+    gives the bits of the 256^3 one."""
+    ints = np.random.default_rng(5).integers(-8, 9, size=(2, 1, 4, 1024, 1024))
+    aq, bq = (jnp.asarray(x, jnp.bfloat16) for x in ints)
+    wide = strassen1_matmul_pallas(aq, bq, out_dtype=jnp.float32)
+    narrow = strassen1_matmul_pallas(
+        aq, bq, block_m=256, block_n=256, block_k=256, out_dtype=jnp.float32
+    )
+    np.testing.assert_array_equal(_bits(wide), _bits(narrow))
+
+
+def test_leaf_counts_block_triples_per_trace():
+    def count(triple):
+        return get_metrics().counter(f"kernels.strassen_leaf.blocks.{triple}").value
+
+    def trace(m2, k2, n2):
+        a = jax.ShapeDtypeStruct((7, 4, m2, k2), jnp.bfloat16)
+        b = jax.ShapeDtypeStruct((7, 4, k2, n2), jnp.bfloat16)
+        jax.eval_shape(
+            lambda x, y: strassen1_matmul_pallas(x, y, out_dtype=jnp.float32), a, b
+        )
+
+    before = count("1024x512x256"), count("128x128x128")
+    trace(2048, 8192, 8192)
+    trace(128, 128, 128)
+    assert (count("1024x512x256"), count("128x128x128")) == (before[0] + 1, before[1] + 1)

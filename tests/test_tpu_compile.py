@@ -92,6 +92,60 @@ def test_strassen1_leaf_compiles_at_256_tiles(one_chip, dtype):
     _assert_kernel(fn.lower(aq, aq).compile())
 
 
+@pytest.mark.parametrize(
+    "a_shape,b_shape",
+    [
+        ((7, 4, 4096, 4096), (7, 4, 4096, 4096)),  # dense16k-fused-d2
+        ((7, 4, 2048, 8192), (7, 4, 8192, 8192)),  # mesh4-32k-fused-d2, per chip
+    ],
+)
+def test_strassen1_leaf_compiles_at_the_cells_wide_tiles(one_chip, a_shape, b_shape):
+    """The cells' depth-2 leaf calls take 1024x512 output tiles, which need
+    more than the compiler's default scoped VMEM: the limit the rule sets
+    lets them compile."""
+    from repro.kernels.strassen import strassen as kernel
+
+    m2, k2, n2 = a_shape[2], a_shape[3], b_shape[3]
+    assert kernel._leaf_blocks(m2, k2, n2, interpret=False) == (1024, 512, 256)
+    fn = jax.jit(
+        lambda a, b: strassen1_matmul_pallas(
+            a, b, out_dtype=jnp.float32, interpret=False
+        )
+    )
+    a = _spec(a_shape, jnp.bfloat16, one_chip)
+    b = _spec(b_shape, jnp.bfloat16, one_chip)
+    _assert_kernel(fn.lower(a, b).compile())
+
+
+@pytest.mark.parametrize(
+    "a_shape,b_shape,out_dtype",
+    [
+        ((7, 4, 768, 1280), (7, 4, 1280, 768), jnp.float32),
+        ((1, 4, 16, 1536), (1, 4, 1536, 4096), jnp.bfloat16),
+    ],
+)
+def test_strassen1_leaf_outside_the_rule_lowers_as_at_256_tiles(
+    one_chip, a_shape, b_shape, out_dtype
+):
+    """Quadrants the wide rule does not take get the kernel explicit 256^3
+    blocks give: the same Mosaic module, with no VMEM limit of its own."""
+
+    def lowered(**blocks):
+        fn = jax.jit(
+            lambda a, b: strassen1_matmul_pallas(
+                a, b, out_dtype=out_dtype, interpret=False, **blocks
+            )
+        )
+        a = _spec(a_shape, jnp.bfloat16, one_chip)
+        b = _spec(b_shape, jnp.bfloat16, one_chip)
+        return fn.lower(a, b).as_text()
+
+    # One call site for both: the kernel's text carries its caller's location.
+    rule, narrow = (lowered(**b) for b in ({}, dict(block_m=256, block_n=256, block_k=256)))
+    assert "scoped_memory_configs" not in rule
+    assert rule == narrow
+
+
 def _entry_opcodes(compiled):
     """Opcode of each instruction in the compiled program's entry computation."""
     text = compiled.as_text()
