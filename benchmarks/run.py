@@ -10,13 +10,11 @@ import sys
 
 def main() -> None:
     from benchmarks import (
-        autotune_sweep,
         fig8_fastest,
         fig8_scaling,
         fig9_partition,
         fig10_theory,
         fig11_stagewise,
-        fig12_scalability,
         roofline_table,
         serve_load,
         spin_scaling,
@@ -26,7 +24,6 @@ def main() -> None:
     )
 
     suites = {
-        "autotune": autotune_sweep.run,
         "fig8": fig8_fastest.run,
         "fig8_scaling": fig8_scaling.run,
         "table6": table6_single_node.run,
@@ -34,7 +31,6 @@ def main() -> None:
         "fig9": fig9_partition.run,
         "fig10": fig10_theory.run,
         "fig11": fig11_stagewise.run,
-        "fig12": fig12_scalability.run,
         "hlo": strassen_hlo.run,
         "roofline": roofline_table.run,
         "serve_load": serve_load.run,

@@ -26,11 +26,11 @@ Phases (one process; each phase's gates fail the run):
                decode loop and a cache-less forward (exact up to a bf16
                near tie, see :func:`phase_serve`); logits are finite.
 
-``--four-chips`` runs strassen_fused_sharded and strassen_bfs_sharded on a
-2x2 mesh of the chips present at 16384^2 bf16 against a sharded naive
-matmul and the f32 reference, checks the output is spread over 4 devices
-and that no device held the whole problem, and executes one mesh autotune
-decision.
+``--four-chips`` runs the mesh route of ``backend.matmul``
+(``MatmulBackend(kind="strassen_fused", mesh=...)``, which runs
+strassen_fused_sharded) on a 2x2 mesh of the chips present at 16384^2 bf16
+against a sharded naive matmul and the f32 reference, and checks the output
+is spread over 4 devices and that no device held the whole problem.
 
 Times printed here are smoke timings (compile included where stated), not
 benchmark numbers. The last stdout line is the result JSON; it is printed
@@ -366,17 +366,13 @@ def phase_serve(cfg=None, seed: int = 0, serve_kw=None) -> None:
 
 # ------------------------------------------------------------- four chips
 def phase_mesh(n: int = 16384, depth: int = 2, seed: int = 3) -> None:
-    import functools
-
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
     from jax.sharding import SingleDeviceSharding
 
-    from repro.core import autotune
-    from repro.core.distributed import strassen_bfs_sharded, strassen_fused_sharded
-    from repro.core.strassen import strassen_matmul
+    from repro.core import backend
     from repro.kernels.strassen.ops import strassen_matmul_fused
 
     devices = jax.devices()
@@ -401,68 +397,45 @@ def phase_mesh(n: int = 16384, depth: int = 2, seed: int = 3) -> None:
     naive = jax.block_until_ready(jax.jit(jnp.matmul, out_shardings=rows)(a, b))
     log(f"mesh {dict(mesh.shape)} n={n} naive rel_err={rel_err(naive, ref):.3e}")
     held = [a, b, ref, naive]
-    strategies = {
-        "strassen_fused_sharded": functools.partial(
-            strassen_fused_sharded, mesh=mesh, depth=depth
-        ),
-        "strassen_bfs_sharded": functools.partial(
-            strassen_bfs_sharded, mesh=mesh, depth=depth
-        ),
-    }
+    # min_dim=1: the phase pins the depth at every n it is given.
+    be = backend.MatmulBackend(kind="strassen_fused", depth=depth, min_dim=1, mesh=mesh)
+    name = "strassen_fused_sharded"
     gate = err_gate(depth)
-    for name, fn in strategies.items():
-        t0 = time.perf_counter()
-        compiled = jax.jit(fn, out_shardings=rows).lower(a, b).compile()
-        compile_s = time.perf_counter() - t0
-        out = jax.block_until_ready(compiled(a, b))
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(compiled(a, b))
-        warm_s = time.perf_counter() - t0
-        err, vs_naive = rel_err(out, ref), rel_err(out, naive.astype(jnp.float32))
-        shard_devs = {s.device for s in out.addressable_shards}
-        shard_shapes = {s.data.shape for s in out.addressable_shards}
-        log(
-            f"mesh {name} depth={depth} rel_err={err:.3e} vs_naive={vs_naive:.3e} "
-            f"shards on {len(shard_devs)} devices, shard shapes {sorted(shard_shapes)} "
-            f"[smoke timing: compile {compile_s:.2f}s, warm call {warm_s:.4f}s]"
-        )
-        check(err <= gate and vs_naive <= gate, f"mesh {name}: error over {gate:.0e}")
-        check(len(shard_devs) == 4, f"mesh {name}: output on {len(shard_devs)} devices")
-        check(shard_shapes == {(n // 4, n)}, f"mesh {name}: shards {shard_shapes}")
-        held.append(out)
-
-    decision = autotune.autotune(n, n, n, jnp.bfloat16, mesh=mesh, max_depth=depth)
-    cand = decision.candidate
-    got = jax.jit(
-        lambda x, y: autotune.execute(cand, x, y, mesh=mesh), out_shardings=rows
-    )(a, b)
-    err = rel_err(got, ref)
-    held.append(got)
-    log(
-        f"mesh autotune decision: kind={decision.kind} scheme={decision.scheme} "
-        f"depth={decision.depth} source={decision.source} executed rel_err={err:.3e}"
+    t0 = time.perf_counter()
+    compiled = (
+        jax.jit(lambda x, y: backend.matmul(x, y, be), out_shardings=rows)
+        .lower(a, b)
+        .compile()
     )
-    gate = err_gate(decision.depth if decision.kind != "naive" else 0)
-    check(err <= gate, f"mesh autotune {decision.kind}: rel_err {err:.3e} > {gate:.0e}")
+    compile_s = time.perf_counter() - t0
+    out = jax.block_until_ready(compiled(a, b))
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(compiled(a, b))
+    warm_s = time.perf_counter() - t0
+    err, vs_naive = rel_err(out, ref), rel_err(out, naive.astype(jnp.float32))
+    shard_devs = {s.device for s in out.addressable_shards}
+    shard_shapes = {s.data.shape for s in out.addressable_shards}
+    log(
+        f"mesh {name} depth={depth} rel_err={err:.3e} vs_naive={vs_naive:.3e} "
+        f"shards on {len(shard_devs)} devices, shard shapes {sorted(shard_shapes)} "
+        f"[smoke timing: compile {compile_s:.2f}s, warm call {warm_s:.4f}s]"
+    )
+    check(err <= gate and vs_naive <= gate, f"mesh {name}: error over {gate:.0e}")
+    check(len(shard_devs) == 4, f"mesh {name}: output on {len(shard_devs)} devices")
+    check(shard_shapes == {(n // 4, n)}, f"mesh {name}: shards {shard_shapes}")
+    held.append(out)
 
     # What device 0 would hold with nothing sharded: every array this phase
-    # keeps, plus the scratch of the largest single-device rendition of the
-    # strategies (compiled for device 0 only, never run).
+    # keeps, plus the scratch of the single-device rendition of the
+    # pipeline (compiled for device 0 only, never run).
     one = jax.ShapeDtypeStruct((n, n), jnp.bfloat16, sharding=SingleDeviceSharding(devices[0]))
-    single = {
-        "strassen_matmul_fused": lambda x, y: strassen_matmul_fused(x, y, depth=depth),
-        "strassen_matmul": lambda x, y: strassen_matmul(x, y, depth=depth),
-    }
-    temps = {
-        k: jax.jit(f).lower(one, one).compile().memory_analysis().temp_size_in_bytes
-        for k, f in single.items()
-    }
-    whole = sum(x.nbytes for x in held) + max(temps.values())
+    single = jax.jit(lambda x, y: strassen_matmul_fused(x, y, depth=depth))
+    temp = single.lower(one, one).compile().memory_analysis().temp_size_in_bytes
+    whole = sum(x.nbytes for x in held) + temp
     peaks = [peak_bytes(d) for d in devices]
     log(
         f"mesh peak_bytes_in_use per device={peaks}; device 0 alone would hold "
-        f"{whole} bytes (arrays {whole - max(temps.values())}, single-device "
-        f"scratch {temps})"
+        f"{whole} bytes (arrays {whole - temp}, single-device scratch {temp})"
     )
     check(all(p > 0 for p in peaks), "mesh: a device reported no memory use")
     check(

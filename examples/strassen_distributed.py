@@ -1,26 +1,25 @@
 """Distributed Strassen on a multi-device mesh (the paper's cluster demo).
 
-Builds its meshes from the devices present and runs the distribution
-strategies that fit them:
-  * strassen_bfs_sharded — Stark/CAPS BFS leaf-batch sharding
-  * strassen_2d          — Luo & Drake Strassen-2D (2D-parallel leaves)
-  * strassen_shardmap    — explicit-collective 7-way level (needs >= 7
-                           devices: one per Strassen product)
+One product spread over a (data, model) mesh of the devices present, the
+way Stark spreads one product's block rows over a cluster's executors:
+``backend.matmul`` with ``MatmulBackend(kind="strassen_fused", mesh=...)``
+splits A and C into row stripes over the mesh, gathers B to every device,
+and runs the fused Strassen pipeline on each device's stripe.
 
-On a TPU host it uses the chips; on a CPU host, force host devices first:
+On a TPU host it uses the chips; on a CPU host, force host devices first
+(the Pallas leaf then runs in interpret mode):
 
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       PYTHONPATH=src python examples/strassen_distributed.py
 """
-import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import AxisType, Mesh
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.distributed import strassen_2d, strassen_bfs_sharded, strassen_shardmap
-from repro.launch.roofline import collective_bytes
+from repro.core import backend
 
 devices = jax.devices()
 print(f"devices: {len(devices)} x {devices[0].device_kind}")
@@ -36,24 +35,27 @@ mesh = Mesh(
     ("data", "model"),
     axis_types=(AxisType.Auto, AxisType.Auto),
 )
+rows = NamedSharding(mesh, P(("data", "model"), None))
 print(f"mesh: {dict(mesh.shape)}")
+a, b = jax.device_put(a, rows), jax.device_put(b, rows)
 
-bfs = jax.jit(functools.partial(strassen_bfs_sharded, mesh=mesh, depth=2, precision="highest"))
-got = bfs(a, b)
-print(f"bfs_sharded   max|err| = {float(jnp.max(jnp.abs(got - want))):.3e}")
+for depth in (1, 2):
+    be = backend.MatmulBackend(
+        kind="strassen_fused", depth=depth, min_dim=32, precision="highest", mesh=mesh
+    )
+    fn = jax.jit(lambda x, y: backend.matmul(x, y, be), out_shardings=rows)
+    got = fn(a, b)
+    shards = {s.device for s in got.addressable_shards}
+    print(
+        f"strassen_fused depth={depth} over {len(shards)} devices "
+        f"max|err| = {float(jnp.max(jnp.abs(got - want))):.3e}"
+    )
 
-s2d = jax.jit(functools.partial(strassen_2d, mesh=mesh, depth=1, precision="highest"))
-got = s2d(a, b)
-print(f"strassen_2d   max|err| = {float(jnp.max(jnp.abs(got - want))):.3e}")
-
-if len(devices) >= 7:
-    mesh7 = Mesh(np.asarray(devices[:7]), ("mult",), axis_types=(AxisType.Auto,))
-    smap = jax.jit(functools.partial(strassen_shardmap, mesh=mesh7, precision="highest"))
-    got = smap(a, b)
-    print(f"shardmap(7)   max|err| = {float(jnp.max(jnp.abs(got - want))):.3e}")
-else:
-    print(f"shardmap(7)   skipped: needs 7 devices, have {len(devices)}")
-
-# show the collective footprint of the BFS pipeline
-txt = bfs.lower(a, b).compile().as_text()
-print("collective bytes (bfs, depth=2):", collective_bytes(txt))
+# the collective footprint: B gathered to every device, nothing else
+txt = fn.lower(a, b).compile().as_text()
+ops = re.findall(r" = (\S+) ([a-z\-]+)\(", txt)
+print(
+    "collectives (mesh route, depth=2):",
+    [f"{op} -> {shape}" for shape, op in ops
+     if op.startswith(("all-", "reduce-scatter", "collective-"))],
+)

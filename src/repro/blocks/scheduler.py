@@ -481,10 +481,6 @@ class PlanScheduler:
             from repro.core.backend import MatmulBackend
 
             backend = MatmulBackend(kind="auto", depth=2, min_dim=1024)
-        # Apply the backend's process-level knobs (XLA latency-hiding /
-        # async-collective flags) once, here — not per leaf call site.
-        if hasattr(backend, "configure"):
-            backend.configure()
         self.backend = backend
 
     # ------------------------------------------------------------ internals

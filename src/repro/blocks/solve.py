@@ -174,8 +174,6 @@ class SolveScheduler:
             from repro.core.backend import MatmulBackend
 
             backend = MatmulBackend(kind="auto", depth=2, min_dim=1024)
-        if hasattr(backend, "configure"):
-            backend.configure()
         self.backend = backend
 
     # ------------------------------------------------------------ execution

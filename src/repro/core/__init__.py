@@ -3,8 +3,10 @@
 Public API:
   coefficients  — Strassen/Winograd/naive8 schemes as constant matrices
   strassen      — serial recursive + batched-BFS Strassen
-  distributed   — mesh-sharded variants (BFS-sharded, Strassen-2D, shard_map)
-  backend       — pluggable matmul routing used by all model layers
+  distributed   — the mesh route: row-sharded Strassen with the fused leaf
+  backend       — pluggable matmul routing used by all model layers; the one
+                  table from a routing kind to the code that runs it
+  autotune      — the calibrated cost model behind kind='auto'
   cost_model    — the paper's §IV stage-wise analytical cost model
 """
 from repro.core.coefficients import STRASSEN, WINOGRAD, NAIVE8, Scheme, get_scheme

@@ -12,17 +12,18 @@ loop, turned into a dispatcher:
    constants ``t_flop`` (seconds per scalar multiply-add) and ``t_elem``
    (seconds per element through a divide/combine level).
 
-2. :func:`enumerate_candidates` lists every strategy that can legally run
-   a given (M, K, N): the naive XLA matmul, batched-BFS Strassen/Winograd
-   at each usable depth, and — when a mesh is supplied — every registered
-   strategy in :data:`repro.core.distributed.MESH_STRATEGIES` whose mesh
-   requirement holds.
+2. :func:`enumerate_candidates` lists every kind that can legally run a
+   given (M, K, N) on one device: the naive XLA matmul, batched-BFS
+   Strassen/Winograd and the Pallas fused-leaf pipeline at each usable
+   depth, and — under a device-memory budget — the out-of-core family.
 
 3. :func:`predict_seconds` costs each candidate with the calibrated
    stage model (divide/combine element traffic * t_elem + leaf flops *
-   t_flop / leaf parallelism); :func:`autotune` picks the argmin, or with
-   ``measure=True`` times the top-k candidates on device and records the
-   measured winner.
+   t_flop); :func:`autotune` picks the argmin, or with ``measure=True``
+   times the top-k candidates on device and records the measured winner.
+   A measurement times ``backend.matmul`` on the backend
+   :func:`repro.core.backend.route_for` makes of the candidate — the
+   route the decision would take, nothing else.
 
 4. :class:`TuningCache` persists decisions as JSON keyed by
    (shape, dtype, device kind+count, scheme set, min_dim, max_depth, and
@@ -31,27 +32,21 @@ loop, turned into a dispatcher:
    re-measurement. Call-site tags let same-shape projections (e.g. a QKV
    and an MLP projection of equal width) diverge under measured mode.
 
-Four constants, three regimes: ``t_flop``/``t_elem`` come from intra-device
-micro-benchmarks; ``t_coll`` is fit separately by
-:func:`calibrate_collective` (an all-gather + reduce-scatter round trip
-over every addressable device) and prices the *interconnect* element
-traffic of the mesh strategies — divide/combine resharding, combine psums,
-SUMMA panel broadcasts; ``t_h2d`` is fit by :func:`calibrate_h2d` (a
-device_put + fetch round trip) and prices the *host<->device staging*
-traffic of the out-of-core ``strassen_oot`` family
-(:mod:`repro.blocks`), whose candidates enumerate when the caller passes
-a device-memory budget. Every resolution is logged to the process
-:class:`Telemetry` (cache hit/miss, chosen kind, predicted-vs-measured
-seconds), which the serving engine exposes in its stats and
-``benchmarks/autotune_sweep.py`` dumps. Real-TPU measured-mode calibration
-remains a ROADMAP follow-on.
+Three constants, two regimes: ``t_flop``/``t_elem`` come from
+intra-device micro-benchmarks; ``t_h2d`` is fit by :func:`calibrate_h2d`
+(a device_put + fetch round trip) and prices the *host<->device staging*
+traffic of the out-of-core ``strassen_oot`` family (:mod:`repro.blocks`),
+whose candidates enumerate when the caller passes a device-memory budget.
+Every resolution is logged to the process :class:`Telemetry` (cache
+hit/miss, chosen kind, predicted-vs-measured seconds), which the serving
+engine exposes in its stats. Real-TPU measured-mode calibration remains a
+ROADMAP follow-on.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import json
-import math
 import os
 import tempfile
 import time
@@ -61,7 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.coefficients import get_scheme
-from repro.core.strassen import divide_level, strassen_matmul
+from repro.core.strassen import divide_level
 from repro.obs import metrics as obs_metrics
 from repro.obs import tracer as obs_tracer
 
@@ -73,7 +68,6 @@ __all__ = [
     "Telemetry",
     "TelemetryEvent",
     "calibrate",
-    "calibrate_collective",
     "calibrate_h2d",
     "get_calibration",
     "calibration_snapshot",
@@ -83,7 +77,6 @@ __all__ = [
     "predict_seconds",
     "predict_cost_terms",
     "measure_seconds",
-    "execute",
     "autotune",
     "autotune_solver",
     "predict_solver_terms",
@@ -93,7 +86,7 @@ __all__ = [
     "warm_for_model",
 ]
 
-# Local (single-program) strategies the backend can dispatch without a mesh.
+# The einsum pipelines' schemes, each also the kind that runs it.
 LOCAL_SCHEMES: Tuple[str, ...] = ("strassen", "winograd")
 # The Pallas fused-leaf pipeline: local, but gated on the leaf running
 # (compat.pallas_leaf_mode) rather than always-legal like the einsum BFS.
@@ -120,8 +113,8 @@ def _oot_pipeline_fits(
     (:func:`repro.blocks.scheduler.pipelined_leaf_bytes`) inside the
     budget at this depth; with less room the scheduler silently degrades
     to synchronous staging, so predictions must not take the overlap
-    discount. A ``None``/0 budget means :func:`execute` will default the
-    budget to exactly one pipelined slot, so the pipeline runs.
+    discount. A ``None``/0 budget means the route defaults the budget to
+    exactly one pipelined slot, so the pipeline runs.
     """
     if not oot_budget:
         return True
@@ -134,17 +127,13 @@ def _oot_pipeline_fits(
 class Candidate:
     """One executable strategy instance for a fixed (M, K, N)."""
 
-    kind: str  # 'naive' | scheme name (local BFS) | registered mesh strategy
+    kind: str  # a routing kind of repro.core.backend (never 'auto')
     scheme: str = "strassen"
     depth: int = 0
 
     @property
     def is_naive(self) -> bool:
         return self.kind == "naive"
-
-    @property
-    def is_local(self) -> bool:
-        return self.kind in ("naive", FUSED_KIND) + LOCAL_SCHEMES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,10 +154,6 @@ class Decision:
     def from_dict(d: Dict) -> "Decision":
         return Decision(**d)
 
-    @property
-    def candidate(self) -> Candidate:
-        return Candidate(kind=self.kind, scheme=self.scheme, depth=self.depth)
-
 
 @dataclasses.dataclass(frozen=True)
 class Calibration:
@@ -178,10 +163,6 @@ class Calibration:
     t_elem: float  # seconds per element through a divide/combine einsum
     device_kind: str = "cpu"
     device_count: int = 1
-    # seconds per element through an interconnect collective (all-gather /
-    # reduce-scatter); 0.0 means "not calibrated" (single device or a
-    # pre-t_coll cache) and predictions fall back to t_elem, the old model.
-    t_coll: float = 0.0
     # seconds per element through host<->device staging (device_put + fetch
     # round trip) — prices the out-of-core pipeline's leaf-wave traffic.
     # 0.0 means "not calibrated" (pre-t_h2d cache); falls back to t_elem.
@@ -192,7 +173,9 @@ class Calibration:
 
     @staticmethod
     def from_dict(d: Dict) -> "Calibration":
-        return Calibration(**d)
+        # Caches written while the autotuner priced mesh strategies carry
+        # their interconnect constant, t_coll; nothing prices it now.
+        return Calibration(**{k: v for k, v in d.items() if k != "t_coll"})
 
 
 def _time_best(fn, repeats: int = 3) -> float:
@@ -204,40 +187,6 @@ def _time_best(fn, repeats: int = 3) -> float:
         fn()
         best = min(best, time.perf_counter() - t0)
     return best
-
-
-def calibrate_collective(sample_dim: int = 512, repeats: int = 3) -> float:
-    """Fit ``t_coll`` from an all-gather + reduce-scatter micro-benchmark.
-
-    A row-sharded (devices * rows, sample_dim) f32 array makes one
-    all-gather and one reduce-scatter round trip over a 1-D mesh of every
-    addressable device — the two collectives GSPMD lowers the mesh
-    strategies' divide/combine reshards and combine psums into. The fit is
-    seconds per element through a collective, the interconnect analogue of
-    ``t_elem`` (which measures an intra-device einsum pass and badly
-    underprices cross-chip traffic). Returns 0.0 on a single device.
-    """
-    d = jax.device_count()
-    if d < 2:
-        return 0.0
-    from jax.sharding import PartitionSpec as P
-
-    from repro.core.compat import make_mesh, shard_map
-
-    mesh = make_mesh((d,), ("coll",))
-    rows = max(1, sample_dim // d) * d
-    x = jnp.ones((rows, sample_dim), jnp.float32)
-
-    def body(x_loc):
-        g = jax.lax.all_gather(x_loc, "coll", tiled=True)
-        return jax.lax.psum_scatter(g, "coll", scatter_dimension=0, tiled=True)
-
-    fn = jax.jit(
-        shard_map(body, mesh=mesh, in_specs=(P("coll", None),), out_specs=P("coll", None))
-    )
-    t = _time_best(lambda: jax.block_until_ready(fn(x)), repeats)
-    # Two full passes of the array through the interconnect (gather + scatter).
-    return t / (2.0 * rows * sample_dim)
 
 
 def calibrate_h2d(sample_dim: int = 1024, repeats: int = 3) -> float:
@@ -271,14 +220,14 @@ def calibrate_h2d(sample_dim: int = 1024, repeats: int = 3) -> float:
 
 
 def calibrate(sample_dim: int = 256, repeats: int = 3) -> Calibration:
-    """Fit (t_flop, t_elem, t_coll) from on-device micro-benchmarks.
+    """Fit (t_flop, t_elem, t_h2d) from on-device micro-benchmarks.
 
     Leaf benchmark: a rank-7 batched matmul — exactly the shape of the BFS
     leaf stage. Divide benchmark: one :func:`divide_level` einsum — exactly
     the divide/combine stage. Both mirror the paper's implicit calibration
-    (it plots theory and experiment in matching units). The collective
-    benchmark (:func:`calibrate_collective`) fits the separate interconnect
-    constant the mesh-strategy terms use.
+    (it plots theory and experiment in matching units). The staging
+    benchmark (:func:`calibrate_h2d`) fits the out-of-core family's
+    constant.
     """
     d = sample_dim
     scheme = get_scheme("strassen")
@@ -304,7 +253,6 @@ def calibrate(sample_dim: int = 256, repeats: int = 3) -> Calibration:
         t_elem=float(t_elem),
         device_kind=dev.device_kind,
         device_count=jax.device_count(),
-        t_coll=float(calibrate_collective(repeats=repeats)),
         t_h2d=float(calibrate_h2d(repeats=repeats)),
     )
 
@@ -331,8 +279,8 @@ def calibration_snapshot() -> Optional[Dict]:
     """The current calibration as a dict, or None if none has run yet.
 
     Never triggers the micro-benchmarks — stats surfaces (e.g.
-    ``Engine.autotune_stats``) use this to report t_flop/t_elem/t_coll/
-    t_h2d without paying device time on an engine that resolved every
+    ``Engine.autotune_stats``) use this to report t_flop/t_elem/t_h2d
+    without paying device time on an engine that resolved every
     decision from a warm cache.
     """
     return _CALIBRATION.to_dict() if _CALIBRATION is not None else None
@@ -361,7 +309,6 @@ def enumerate_candidates(
     schemes: Sequence[str] = LOCAL_SCHEMES,
     max_depth: int = 3,
     min_dim: int = 1024,
-    mesh=None,
     oot_budget: Optional[int] = None,
     dtype=jnp.float32,
 ) -> List[Candidate]:
@@ -386,17 +333,6 @@ def enumerate_candidates(
     if depths and "strassen" in schemes and compat.pallas_leaf_mode() != "none":
         for d in depths:
             cands.append(Candidate(kind=FUSED_KIND, scheme="strassen", depth=d))
-    if mesh is not None and depths:
-        from repro.core.distributed import available_strategies
-
-        for scheme in schemes:
-            for name in available_strategies(mesh, scheme):
-                if name.startswith("strassen_shardmap"):
-                    # explicit one-level renditions
-                    cands.append(Candidate(kind=name, scheme=scheme, depth=1))
-                else:
-                    for d in depths:
-                        cands.append(Candidate(kind=name, scheme=scheme, depth=d))
     if oot_budget:
         from repro.blocks.scheduler import leaf_bytes, min_depth_for_budget
 
@@ -424,9 +360,8 @@ def enumerate_candidates(
                     ) >= 2**d:
                         cands.append(Candidate(kind=OOT_KIND, scheme=scheme, depth=d))
         # When the dense working set exceeds the budget every on-device
-        # candidate (mesh strategies included: the budget models each
-        # device's memory) is infeasible, not merely slow — drop them so
-        # the planner cannot pick an impossible plan. Runs LAST so the
+        # candidate is infeasible, not merely slow — drop them so the
+        # planner cannot pick an impossible plan. Runs LAST so the
         # invariant holds over the full candidate set. (Falls back to the
         # unfiltered list if no oot depth fits either, so callers still
         # get a best-effort decision.)
@@ -448,16 +383,15 @@ def predict_cost_terms(
     n: int,
     calib: Calibration,
     *,
-    device_count: int = 1,
     oot_overlap: bool = True,
 ) -> Dict[str, float]:
     """Per-constant cost decomposition of one candidate's predicted seconds.
 
-    Returns ``{"t_flop": ..., "t_elem": ..., "t_coll": ..., "t_h2d": ...}``
-    — the seconds attributed to each calibrated constant, summing to
-    :func:`predict_seconds`. The split is what telemetry and the sweep
-    report: it shows *why* a candidate wins (compute vs local traffic vs
-    interconnect vs host<->device staging).
+    Returns ``{"t_flop": ..., "t_elem": ..., "t_h2d": ...}`` — the seconds
+    attributed to each calibrated constant, summing to
+    :func:`predict_seconds`. The split is what telemetry reports: it shows
+    *why* a candidate wins (compute vs local traffic vs host<->device
+    staging).
 
     ``oot_overlap`` models the scheduler's async wave pipeline (its
     default): staging traffic that fits under the leaf compute is hidden,
@@ -468,21 +402,14 @@ def predict_cost_terms(
     where every staged byte is on the critical path.
     """
     flops_naive = 2.0 * m * k * n
-    t_coll = calib.t_coll if calib.t_coll > 0.0 else calib.t_elem
-    terms = {"t_flop": 0.0, "t_elem": 0.0, "t_coll": 0.0, "t_h2d": 0.0}
+    terms = {"t_flop": 0.0, "t_elem": 0.0, "t_h2d": 0.0}
     if cand.is_naive:
-        # On a mesh the naive matmul 2D-parallelizes fully (MLLib regime),
-        # but pays the SUMMA panel broadcasts — the JAX analogue of MLLib's
-        # 2bn^2 coGroup shuffle (paper Table I), and the term Strassen's
-        # fewer leaves undercut at scale.
-        terms["t_flop"] = flops_naive * calib.t_flop / max(device_count, 1)
-        if device_count > 1:
-            terms["t_coll"] = k * (m + n) * math.sqrt(device_count) * t_coll
+        terms["t_flop"] = flops_naive * calib.t_flop
         return terms
 
     rank = get_scheme(cand.scheme).n_mults
     l = cand.depth
-    fused = cand.kind in (FUSED_KIND, "strassen_fused_sharded")
+    fused = cand.kind == FUSED_KIND
     # Levels whose intermediates are materialized: all l for the einsum
     # pipelines, l-1 when the last level runs fused in VMEM.
     lm = l - 1 if fused else l
@@ -500,13 +427,15 @@ def predict_cost_terms(
         # 7/4x M-term blowup never touches HBM.
         elem_cost += rank ** (l - 1) * (m * k + k * n + m * n) / 4.0 ** (l - 1)
     leaf_flops = flops_naive * (rank / 8.0) ** l
+    terms["t_flop"] = leaf_flops * calib.t_flop
+    terms["t_elem"] = elem_cost * calib.t_elem
 
     if cand.kind == OOT_KIND:
         # Out-of-core: divide/combine adds are host-side element traffic;
-        # leaf waves run sequentially on one device (PF=1) and every leaf's
+        # leaf waves run sequentially on one device and every leaf's
         # operands cross the host<->device boundary once each way.
         t_h2d = calib.t_h2d if calib.t_h2d > 0.0 else calib.t_elem
-        flop_s = leaf_flops * calib.t_flop
+        flop_s = terms["t_flop"]
         h2d_s = rank**l * (m * k + k * n + m * n) / 4.0**l * t_h2d
         if oot_overlap:
             # Async pipeline: staging overlaps leaf compute, so only the
@@ -514,50 +443,7 @@ def predict_cost_terms(
             # fill/drain bubble, a fixed fraction of the hidden portion.
             hidden = min(h2d_s, flop_s)
             h2d_s = max(h2d_s - flop_s, 0.0) + OOT_OVERLAP_EXPOSED_FRACTION * hidden
-        terms["t_flop"] = flop_s
-        terms["t_elem"] = elem_cost * calib.t_elem
         terms["t_h2d"] = h2d_s
-        return terms
-
-    coll_cost = 0.0
-    if cand.is_local:
-        leaf_pf = 1.0
-        elem_pf = 1.0
-        elem_key = "t_elem"
-        t_comm = calib.t_elem
-    elif cand.kind == "strassen_fused_sharded":
-        # Row-parallel over every mesh axis (the strategy row-shards across
-        # the full device grid): every stage runs per-device on local
-        # stripes; the only interconnect term is replicating B to every
-        # row shard.
-        leaf_pf = float(device_count)
-        elem_pf = float(device_count)
-        elem_key = "t_elem"
-        t_comm = calib.t_elem
-        coll_cost = k * n * t_coll
-    elif cand.kind == "strassen_2d":
-        # 2D-parallel leaves spread each block product over the mesh;
-        # the leaf batch stays replicated so combine is collective-free,
-        # but divide/combine traffic reshards across the grid.
-        leaf_pf = float(device_count)
-        elem_pf = 1.0
-        elem_key = "t_coll"
-        t_comm = t_coll
-    elif cand.kind.startswith("strassen_shardmap"):
-        # one explicit BFS level over the whole grid (mult times rows /
-        # rb*cb axes all carry leaf work); combine is a single psum of C.
-        leaf_pf = float(device_count)
-        elem_pf = 1.0
-        elem_key = "t_coll"
-        t_comm = t_coll
-    else:  # strassen_bfs_sharded and future BFS-batch strategies
-        leaf_pf = float(min(rank**l, device_count))
-        elem_pf = 1.0
-        elem_key = "t_coll"
-        t_comm = t_coll
-    terms["t_flop"] = leaf_flops * calib.t_flop / leaf_pf
-    terms[elem_key] += elem_cost * t_comm / elem_pf
-    terms["t_coll"] += coll_cost
     return terms
 
 
@@ -568,94 +454,25 @@ def predict_seconds(
     n: int,
     calib: Calibration,
     *,
-    device_count: int = 1,
     oot_overlap: bool = True,
 ) -> float:
     """Predicted wall-clock for one multiply under the calibrated model.
 
     Mirrors :mod:`repro.core.cost_model`: each divide/combine level costs
-    its output-element traffic * a per-element constant; the leaf stage
-    costs its flops * t_flop divided by the leaf parallelization factor
-    (paper's PF, min'd with the device count). Single-program candidates
-    have PF = 1: XLA already uses the whole device, which is what t_flop
-    measures. Element traffic that crosses the interconnect — mesh-strategy
-    resharding, combine psums, SUMMA panel broadcasts — is priced at
-    ``t_coll`` (falling back to ``t_elem`` for pre-t_coll calibrations);
-    local HBM traffic stays at ``t_elem``. Fused-leaf candidates skip the
-    last level's materialized traffic. Out-of-core candidates add the
-    host<->device staging term priced at ``t_h2d`` — discounted to the
-    exposed traffic when ``oot_overlap`` is on (the scheduler's async
-    pipeline default). See :func:`predict_cost_terms` for the per-constant
-    decomposition.
+    its output-element traffic * ``t_elem``; the leaf stage costs its flops
+    * ``t_flop`` (one program: XLA already uses the whole device, which is
+    what t_flop measures). Fused-leaf candidates skip the last level's
+    materialized traffic. Out-of-core candidates add the host<->device
+    staging term priced at ``t_h2d`` — discounted to the exposed traffic
+    when ``oot_overlap`` is on (the scheduler's async pipeline default).
+    See :func:`predict_cost_terms` for the per-constant decomposition.
     """
-    return sum(
-        predict_cost_terms(
-            cand, m, k, n, calib, device_count=device_count, oot_overlap=oot_overlap
-        ).values()
-    )
+    return sum(predict_cost_terms(cand, m, k, n, calib, oot_overlap=oot_overlap).values())
 
 
 # --------------------------------------------------------------------------
-# Execution + measurement
+# Measurement
 # --------------------------------------------------------------------------
-
-
-def execute(
-    cand: Candidate,
-    a: jax.Array,
-    b: jax.Array,
-    *,
-    precision=None,
-    mesh=None,
-    oot_budget: Optional[int] = None,
-) -> jax.Array:
-    """Run one candidate. Raises KeyError for unknown mesh strategy names.
-
-    ``strassen_oot`` candidates run the host-resident block pipeline
-    eagerly (they cannot trace under jit); ``oot_budget`` caps their
-    device bytes, defaulting to one single-leaf pipelined wave slot.
-    """
-    if cand.is_naive:
-        return jnp.matmul(a, b, precision=precision)
-    if cand.kind == OOT_KIND:
-        import numpy as np
-
-        from repro.blocks.scheduler import pipelined_leaf_bytes, strassen_oot_matmul
-
-        a_h, b_h = np.asarray(a), np.asarray(b)
-        m, k = a_h.shape
-        n = b_h.shape[1]
-        dtype = np.result_type(a_h.dtype, b_h.dtype)
-        budget = oot_budget or pipelined_leaf_bytes(m, k, n, cand.depth, dtype)
-        leaf_backend = None
-        if precision is not None:
-            # Thread the caller's precision into the leaf waves — measured
-            # comparisons must price every candidate at the same precision.
-            from repro.core.backend import MatmulBackend
-
-            leaf_backend = MatmulBackend(kind="auto", depth=2, precision=precision)
-        out, _ = strassen_oot_matmul(
-            a_h, b_h, depth=cand.depth, budget_bytes=budget, scheme=cand.scheme,
-            backend=leaf_backend,
-        )
-        return jnp.asarray(out)
-    if cand.kind == FUSED_KIND:
-        from repro.kernels.strassen.ops import strassen_matmul_fused_padded
-
-        return strassen_matmul_fused_padded(
-            a, b, depth=cand.depth, scheme_name=cand.scheme, precision=precision
-        )
-    if cand.kind in LOCAL_SCHEMES:
-        return strassen_matmul(
-            a, b, depth=cand.depth, scheme=cand.scheme, precision=precision
-        )
-    from repro.core.distributed import get_strategy
-
-    fn = get_strategy(cand.kind)
-    kwargs = {"mesh": mesh, "scheme": cand.scheme, "precision": precision}
-    if not cand.kind.startswith("strassen_shardmap"):
-        kwargs["depth"] = cand.depth
-    return fn(a, b, **kwargs)
 
 
 def measure_seconds(
@@ -665,28 +482,39 @@ def measure_seconds(
     n: int,
     dtype=jnp.float32,
     *,
-    mesh=None,
+    min_dim: int = 1024,
     precision=None,
     repeats: int = 2,
     oot_budget: Optional[int] = None,
 ) -> float:
-    """Time one candidate end-to-end on device (compile excluded)."""
+    """Time one candidate's route end-to-end on device (compile excluded).
+
+    The candidate runs as ``backend.matmul`` on the backend
+    :func:`repro.core.backend.route_for` makes of it, with the caller's
+    ``min_dim``, ``precision`` and ``oot_budget`` — exactly the route a
+    decision for it takes, out-of-core depth deepening included. Jitted
+    for the in-device kinds; eager for the host-resident ones, on host
+    operands.
+    """
+    from repro.core import backend as _backend
+
+    route = _backend.route_for(
+        cand,
+        _backend.MatmulBackend(
+            kind="auto", min_dim=min_dim, precision=precision, device_budget=oot_budget
+        ),
+    )
     ka, kb = jax.random.split(jax.random.PRNGKey(0))
     a = jax.random.normal(ka, (m, k), jnp.float32).astype(dtype)
     b = jax.random.normal(kb, (k, n), jnp.float32).astype(dtype)
-    if cand.kind == OOT_KIND:
-        # Host-resident pipeline: eager by construction, warmup still
-        # excludes the leaf dispatch's trace/compile cost.
+    if route.kind in _backend.EAGER_ONLY_KINDS:
         import numpy as np
 
         a_h, b_h = np.asarray(a), np.asarray(b)
         return _time_best(
-            lambda: jax.block_until_ready(
-                execute(cand, a_h, b_h, precision=precision, oot_budget=oot_budget)
-            ),
-            repeats,
+            lambda: jax.block_until_ready(_backend.matmul(a_h, b_h, route)), repeats
         )
-    fn = jax.jit(lambda x, y: execute(cand, x, y, precision=precision, mesh=mesh))
+    fn = jax.jit(lambda x, y: _backend.matmul(x, y, route))
     return _time_best(lambda: jax.block_until_ready(fn(a, b)), repeats)
 
 
@@ -710,9 +538,9 @@ def cache_key(
     site: Optional[str] = None,
     oot_budget: Optional[int] = None,
 ) -> str:
-    """``topo`` separates local from mesh resolutions: the candidate sets and
-    cost models differ, so a mesh decision must never answer a local lookup
-    (or vice versa) even at equal device counts.
+    """``topo`` separates candidate families: the matmul kinds resolve under
+    ``"local"``, each solver family under its own name, so an entry of one
+    can never answer a lookup of another.
 
     ``site`` is an optional call-site tag (e.g. ``"attn.wq"``) threaded from
     the model stack: tagged entries are keyed per call site, so same-shape
@@ -817,7 +645,7 @@ class TelemetryEvent:
     cache_hit: bool
     predicted_s: float
     measured_s: Optional[float] = None
-    # Per-constant decomposition of predicted_s (t_flop/t_elem/t_coll/t_h2d
+    # Per-constant decomposition of predicted_s (t_flop/t_elem/t_h2d
     # seconds, see predict_cost_terms). None on cache hits: the stored
     # decision predates this resolution and its calibration may differ.
     terms: Optional[Dict[str, float]] = None
@@ -935,7 +763,6 @@ def autotune(
     calibration: Optional[Calibration] = None,
     measure: bool = False,
     top_k: int = 3,
-    mesh=None,
     precision=None,
     site: Optional[str] = None,
     oot_budget: Optional[int] = None,
@@ -945,7 +772,8 @@ def autotune(
 
     Cache hits return immediately (source='cache') — before calibration, so
     a warm cache costs zero device time. ``measure=True`` times the top-k
-    predicted candidates and records the measured winner, the
+    predicted candidates, each through its own route
+    (:func:`measure_seconds`), and records the measured winner, the
     theory-vs-practice loop of the paper's §V.
 
     ``site`` keys the decision per call site (see :func:`cache_key`). In
@@ -961,26 +789,19 @@ def autotune(
     tel = telemetry if telemetry is not None else _TELEMETRY
     # Every resolution is a span: cache hits close immediately with
     # cache_hit=True; fresh decisions carry the predicted cost-term
-    # breakdown (t_flop/t_elem/t_coll/t_h2d) next to any measured time —
+    # breakdown (t_flop/t_elem/t_h2d) next to any measured time —
     # the predicted-vs-measured feed the TPU recalibration item needs.
     tr = obs_tracer.get_tracer()
     res_span = tr.begin(
         "autotune.resolve", cat="autotune", site=site, m=m, k=k, n=n,
     )
     dev = jax.devices()[0]
-    if mesh is not None:
-        device_count = len(mesh.devices.flatten())
-        topo = "mesh" + "x".join(str(s) for s in mesh.devices.shape)
-    else:
-        device_count = 1
-        topo = "local"
     key_kwargs = dict(
         device_kind=dev.device_kind,
-        device_count=device_count,
+        device_count=1,
         schemes=schemes,
         min_dim=min_dim,
         max_depth=max_depth,
-        topo=topo,
         oot_budget=oot_budget,
     )
     key = cache_key(m, k, n, dtype, site=site, **key_kwargs)
@@ -988,7 +809,7 @@ def autotune(
         hit = cache.get(key)
         if hit is None and site and not measure:
             hit = cache.get(cache_key(m, k, n, dtype, **key_kwargs))
-        if hit is not None and hit.kind in (FUSED_KIND, "strassen_fused_sharded"):
+        if hit is not None and hit.kind == FUSED_KIND:
             # Re-validate fused decisions against THIS host: a cache warmed
             # where the Pallas leaf ran must not route to it on a build
             # where it cannot (enumeration would have excluded it).
@@ -1022,7 +843,7 @@ def autotune(
 
     calib = calibration or _cached_calibration(cache, dev) or get_calibration()
     cands = enumerate_candidates(
-        m, k, n, schemes=schemes, max_depth=max_depth, min_dim=min_dim, mesh=mesh,
+        m, k, n, schemes=schemes, max_depth=max_depth, min_dim=min_dim,
         oot_budget=oot_budget, dtype=dtype,
     )
 
@@ -1036,21 +857,15 @@ def autotune(
         )
 
     scored = sorted(
-        cands,
-        key=lambda c: predict_seconds(
-            c, m, k, n, calib, device_count=device_count, oot_overlap=_overlap(c)
-        ),
+        cands, key=lambda c: predict_seconds(c, m, k, n, calib, oot_overlap=_overlap(c))
     )
     best = scored[0]
-    predicted = predict_seconds(
-        best, m, k, n, calib, device_count=device_count, oot_overlap=_overlap(best)
-    )
     measured = None
     if measure:
         timed = [
             (
                 measure_seconds(
-                    c, m, k, n, dtype, mesh=mesh, precision=precision,
+                    c, m, k, n, dtype, min_dim=min_dim, precision=precision,
                     oot_budget=oot_budget,
                 ),
                 c,
@@ -1058,10 +873,8 @@ def autotune(
             for c in scored[: max(top_k, 1)]
         ]
         measured, best = min(timed, key=lambda t: t[0])
-        predicted = predict_seconds(
-            best, m, k, n, calib, device_count=device_count,
-            oot_overlap=_overlap(best),
-        )
+    terms = predict_cost_terms(best, m, k, n, calib, oot_overlap=_overlap(best))
+    predicted = sum(terms.values())
 
     decision = Decision(
         kind=best.kind,
@@ -1082,10 +895,6 @@ def autotune(
         )
         cache.put(store_key, decision)
         cache.save()
-    terms = predict_cost_terms(
-        best, m, k, n, calib, device_count=device_count,
-        oot_overlap=_overlap(best),
-    )
     tel.record(
         TelemetryEvent(
             key=key,

@@ -16,7 +16,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -33,14 +32,13 @@ __all__ = [
     "NAIVE_BACKEND",
     "AUTO_BACKEND",
     "resolve_auto",
+    "route_for",
     "VALID_KINDS",
     "EAGER_ONLY_KINDS",
     "JIT_SAFE_KINDS",
     "SOLVER_KINDS",
     "SOLVER_EAGER_ONLY_KINDS",
     "SOLVER_JIT_SAFE_KINDS",
-    "XLA_ASYNC_FLAGS",
-    "enable_xla_async_flags",
     "set_default_matmul_precision",
     "default_matmul_precision",
     "resolve_precision",
@@ -60,7 +58,8 @@ VALID_KINDS: Tuple[str, ...] = (
 )
 
 # The one kind that can spread a product over a MatmulBackend.mesh, and the
-# registered strategy (core/distributed.py) it runs there.
+# name the backend.matmul span gives the function that runs it there
+# (core/distributed.py).
 MESH_KIND = "strassen_fused"
 MESH_STRATEGY = "strassen_fused_sharded"
 
@@ -82,17 +81,6 @@ SOLVER_KINDS: Tuple[str, ...] = ("dense", "spin_oot", "auto")
 SOLVER_EAGER_ONLY_KINDS: Tuple[str, ...] = ("spin_oot",)
 SOLVER_JIT_SAFE_KINDS: Tuple[str, ...] = tuple(
     k for k in SOLVER_KINDS if k not in SOLVER_EAGER_ONLY_KINDS
-)
-
-# XLA flags that let the compiler overlap collectives and transfers with
-# compute (the bayespec config.py GPU recipe): the scheduler-level analogue
-# of the out-of-core wave pipeline. They only take effect if appended to
-# XLA_FLAGS before the jax backend initializes — enable_xla_async_flags()
-# reports which regime it ran in.
-XLA_ASYNC_FLAGS: Tuple[str, ...] = (
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_async_collectives=true",
-    "--xla_gpu_enable_highest_priority_async_stream=true",
 )
 
 # Process-default matmul precision, the HomebrewNLP backend.py discipline:
@@ -134,27 +122,6 @@ def precision_scope(precision: Optional[str]):
     if precision is None or precision == "fastest":
         return contextlib.nullcontext()
     return jax.default_matmul_precision(precision)
-
-
-def enable_xla_async_flags(flags: Tuple[str, ...] = XLA_ASYNC_FLAGS) -> bool:
-    """Append latency-hiding/async-collective flags to ``XLA_FLAGS``.
-
-    Idempotent: flags already present (under any value) are left alone.
-    Returns True when the jax backend has not initialized yet — i.e. the
-    flags will actually reach XLA — and False when they can only take
-    effect in a future process (set XLA_FLAGS before the first jax call).
-    """
-    current = os.environ.get("XLA_FLAGS", "")
-    missing = [f for f in flags if f.split("=", 1)[0] not in current]
-    if missing:
-        os.environ["XLA_FLAGS"] = " ".join(([current] if current else []) + missing)
-    try:  # private, so probed defensively: absence just means "unknown"
-        from jax._src import xla_bridge
-
-        initialized = bool(xla_bridge._backends)
-    except Exception:  # pragma: no cover - jax internals moved
-        initialized = False
-    return not initialized
 
 
 def is_oom_error(exc: BaseException) -> bool:
@@ -199,10 +166,6 @@ class MatmulBackend:
         'highest'...). None inherits the process default set via
         :func:`set_default_matmul_precision` — precision policy is a
         backend knob, not a per-call-site argument.
-      latency_hiding: apply :data:`XLA_ASYNC_FLAGS` (latency-hiding
-        scheduler + async collectives) once via :meth:`configure` — called
-        by the surfaces that own a backend for a whole run (serving
-        engine, out-of-core scheduler), never per call site.
       tuning_cache: optional path to a persistent autotune JSON cache
         ('auto' only). Decisions found there are reused verbatim — the
         serving engine points this at its warmed startup cache.
@@ -230,7 +193,6 @@ class MatmulBackend:
     measure: bool = False
     schemes: Tuple[str, ...] = ("strassen", "winograd")
     device_budget: Optional[int] = None
-    latency_hiding: bool = False
     mesh: Optional[jax.sharding.Mesh] = None
 
     def __post_init__(self):
@@ -244,17 +206,6 @@ class MatmulBackend:
                 f"backend kind {self.kind!r} cannot take a mesh; only "
                 f"{MESH_KIND!r} can"
             )
-
-    def configure(self) -> "MatmulBackend":
-        """Apply the backend's process-level knobs once (idempotent).
-
-        Today that is the XLA latency-hiding/async-collective flag set;
-        call it from the surface that owns the backend for a run (Engine
-        startup, scheduler construction) rather than per matmul.
-        """
-        if self.latency_hiding:
-            enable_xla_async_flags()
-        return self
 
     @property
     def scheme_name(self) -> str:
@@ -320,23 +271,30 @@ def resolve_auto(
         schemes=backend.schemes,
         cache=cache,
         measure=backend.measure,
+        precision=backend.precision,
         site=site,
         oot_budget=backend.device_budget,
     )
-    if decision.kind == "naive":
-        return dataclasses.replace(backend, kind="naive", measure=False)
-    if decision.kind in ("strassen_fused", "strassen_oot"):
-        # schemes pins scheme_name to the decision's scheme (the oot
-        # family enumerates winograd too; fused is strassen-only today).
-        return dataclasses.replace(
-            backend,
-            kind=decision.kind,
-            depth=decision.depth,
-            schemes=(decision.scheme,),
-            measure=False,
-        )
+    return route_for(decision, backend)
+
+
+def route_for(choice, backend: MatmulBackend) -> MatmulBackend:
+    """The concrete backend that runs ``choice`` in place of ``backend``.
+
+    ``choice`` is an autotune ``Decision`` or ``Candidate``: its kind is a
+    routing kind, and its scheme rides along as the single ``schemes``
+    entry (the out-of-core runtime executes any scheme; the einsum
+    pipelines name theirs by kind). Everything else — min_dim, precision,
+    device_budget, tuning_cache — stays the caller's. This is the one
+    mapping from a decision to its route: :func:`resolve_auto` routes by it
+    and measured autotuning times what it returns.
+    """
     return dataclasses.replace(
-        backend, kind=decision.scheme, depth=decision.depth, measure=False
+        backend,
+        kind=choice.kind,
+        depth=choice.depth,
+        schemes=(choice.scheme,),
+        measure=False,
     )
 
 
@@ -450,7 +408,8 @@ def matmul(
 
 
 def _matmul_mesh(x, w, backend: MatmulBackend, w_logical, lead, m: int, k: int, n: int):
-    """One product spread over ``backend.mesh`` by :data:`MESH_STRATEGY`.
+    """One product spread over ``backend.mesh`` by
+    :func:`repro.core.distributed.strassen_fused_sharded`.
 
     The depth is the backend's effective depth for one device's row stripe
     (M over the row shards, rounded up: the strategy pads M to the stripe
@@ -474,8 +433,7 @@ def _matmul_mesh(x, w, backend: MatmulBackend, w_logical, lead, m: int, k: int, 
             f"(even dims, all >= min_dim={backend.min_dim}); use mesh=None "
             f"for smaller products"
         )
-    strategy = distributed.get_strategy(MESH_STRATEGY)
-    out = strategy(
+    out = distributed.strassen_fused_sharded(
         x.reshape(m, k), w, mesh=backend.mesh, depth=depth,
         scheme=backend.scheme_name, precision=resolve_precision(backend),
     )

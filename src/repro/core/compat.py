@@ -32,8 +32,8 @@ def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], **kwargs):
 def shard_map(f, *, mesh, in_specs, out_specs):
     """Replication-unchecked ``jax.shard_map``.
 
-    The Strassen shardmap bodies psum partial products whose replication
-    XLA cannot infer, so the ``check_vma`` verifier must be off.
+    The mesh route's body is a Pallas call whose output shape carries no
+    varying-mesh-axes annotation, so the ``check_vma`` verifier must be off.
     """
     return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False

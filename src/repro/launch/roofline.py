@@ -125,12 +125,12 @@ def roofline_terms(
     scale = 1.0 if per_device else 1.0 / chips
     t_compute = hlo_flops * scale / hw.peak_flops
     t_memory = hlo_bytes * scale / hw.hbm_bw
-    t_coll = coll_bytes * scale / hw.ici_bw
+    t_collective = coll_bytes * scale / hw.ici_bw
     terms = {
         "compute_s": t_compute,
         "memory_s": t_memory,
-        "collective_s": t_coll,
+        "collective_s": t_collective,
     }
     terms["bottleneck"] = max(terms, key=lambda k: terms[k])[: -2]
-    terms["bound_s"] = max(t_compute, t_memory, t_coll)
+    terms["bound_s"] = max(t_compute, t_memory, t_collective)
     return terms

@@ -175,9 +175,6 @@ class Engine:
         # TPOT), pool-page gauges, token counters. Engine-scoped for the
         # same isolation reason as the ring; surfaced by stats()["obs"].
         self.metrics = obs_metrics.Metrics()
-        # Apply process-level backend knobs (XLA latency-hiding flags)
-        # once per run, here rather than per call site.
-        cfg.matmul_backend.configure()
         cfg = serve_cfg.apply_to(cfg)
         if cfg.matmul_backend.kind == "auto":
             # decode resolves at 1 token/seq; prefill at up to max_seq tokens
@@ -1063,7 +1060,7 @@ class Engine:
         """Full autotune telemetry snapshot plus the calibration it ran on.
 
         Each fresh decision carries its per-constant cost split under
-        ``terms`` (t_flop/t_elem/t_coll seconds, and t_h2d for the
+        ``terms`` (t_flop/t_elem seconds, and t_h2d for the
         out-of-core ``strassen_oot`` family); ``calibration`` reports the
         fitted constants themselves (None when every decision came from a
         warm cache and no calibration ever ran). ``oot`` carries the

@@ -1,5 +1,6 @@
 """Autotuned kind='auto' dispatcher: correctness, guards, cache, cost model."""
 import dataclasses
+import json
 import os
 
 import jax
@@ -22,7 +23,8 @@ from repro.core.autotune import (
     enumerate_candidates,
     predict_seconds,
 )
-from repro.core.backend import MatmulBackend, matmul, resolve_auto
+from repro.core import backend as backend_mod
+from repro.core.backend import MatmulBackend, matmul, resolve_auto, route_for
 from repro.core.cost_model import paper_stage_count, total_cost
 
 RNG = np.random.default_rng(17)
@@ -168,6 +170,44 @@ def test_cache_key_separates_dtype_and_shape():
     assert len({k1, k2, k3}) == 3
 
 
+def _parent_cache(path, key):
+    with open(path, "w") as f:
+        json.dump({
+            "decisions": {key: {
+                "kind": "strassen", "scheme": "strassen", "depth": 1,
+                "predicted_s": 1e-3, "measured_s": None, "source": "predicted",
+            }},
+            "calibration": {
+                "t_flop": 1e-11, "t_elem": 1e-9, "device_kind": "test",
+                "device_count": 1, "t_coll": 4e-9, "t_h2d": 2e-9,
+            },
+        }, f)
+
+
+def test_parent_format_cache_with_t_coll_loads(tmp_path):
+    """A cache written while the autotuner priced mesh strategies carries
+    t_coll in its calibration: it loads, and the key is dropped."""
+    path = os.path.join(tmp_path, "tuning.json")
+    _parent_cache(path, "k")
+    cache = TuningCache(path)
+    assert cache.calibration == Calibration(
+        t_flop=1e-11, t_elem=1e-9, device_kind="test", device_count=1, t_h2d=2e-9
+    )
+    assert "t_coll" not in cache.calibration.to_dict()
+    assert cache.get("k").kind == "strassen"
+
+
+def test_local_cache_keys_are_unchanged(tmp_path):
+    """Local matmul decisions keep their key byte for byte, so a cache a
+    parent-format process wrote still answers them."""
+    dev = jax.devices()[0].device_kind
+    key = f"512x512x512|float32|{dev}:1|local|strassen,winograd|min1024|d2"
+    path = os.path.join(tmp_path, "tuning.json")
+    _parent_cache(path, key)
+    d = autotune.autotune(512, 512, 512, max_depth=2, cache=TuningCache(path))
+    assert (d.source, d.kind, d.depth) == ("cache", "strassen", 1)
+
+
 def test_backend_resolution_is_cached_per_shape(monkeypatch):
     be = _auto_backend(min_dim=1)
     calls = []
@@ -281,9 +321,10 @@ def test_fused_selected_at_scale_and_executes():
     shape — interpret-mode Pallas at 8192 would dominate suite time)."""
     d = autotune.autotune(8192, 8192, 8192, calibration=CALIB, min_dim=1024)
     assert d.kind == "strassen_fused" and d.depth >= 1
-    small = Candidate(kind="strassen_fused", scheme="strassen", depth=d.depth)
+    be = route_for(d, MatmulBackend(min_dim=1))
+    assert (be.kind, be.depth) == ("strassen_fused", d.depth)
     x, w = _rand((256, 256)), _rand((256, 256))
-    got = autotune.execute(small, x, w)
+    got = matmul(x, w, be)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(x @ w), atol=3e-3, rtol=3e-3
     )
@@ -316,61 +357,6 @@ def test_fused_predicted_cheaper_than_unfused_strassen():
         assert predict_seconds(fused, n, n, n, CALIB) < predict_seconds(
             plain, n, n, n, CALIB
         )
-
-
-# ------------------------------------------------------- t_coll cost model
-def test_t_coll_monotonicity():
-    """Mesh-strategy predictions are strictly increasing in t_coll; local
-    candidates never touch the interconnect constant."""
-    n, dc = 4096, 8
-    mesh_kinds = [
-        Candidate(kind="strassen_bfs_sharded", scheme="strassen", depth=2),
-        Candidate(kind="strassen_2d", scheme="strassen", depth=2),
-        Candidate(kind="strassen_fused_sharded", scheme="strassen", depth=2),
-        Candidate(kind="naive"),
-    ]
-    local_kinds = [
-        Candidate(kind="strassen", scheme="strassen", depth=2),
-        Candidate(kind="strassen_fused", scheme="strassen", depth=2),
-    ]
-    t_colls = [1e-9, 4e-9, 1.6e-8, 6.4e-8]
-    for cand in mesh_kinds:
-        costs = [
-            predict_seconds(
-                cand, n, n, n,
-                dataclasses.replace(CALIB, t_coll=tc, device_count=dc),
-                device_count=dc,
-            )
-            for tc in t_colls
-        ]
-        assert all(a < b for a, b in zip(costs, costs[1:])), (cand.kind, costs)
-    for cand in local_kinds:
-        costs = {
-            predict_seconds(
-                cand, n, n, n,
-                dataclasses.replace(CALIB, t_coll=tc, device_count=dc),
-                device_count=dc,
-            )
-            for tc in t_colls
-        }
-        assert len(costs) == 1, (cand.kind, costs)
-
-
-def test_t_coll_zero_falls_back_to_t_elem():
-    """Pre-t_coll calibrations (t_coll=0) must reproduce the old model."""
-    cand = Candidate(kind="strassen_bfs_sharded", scheme="strassen", depth=1)
-    base = predict_seconds(cand, 2048, 2048, 2048, CALIB, device_count=8)
-    explicit = predict_seconds(
-        cand, 2048, 2048, 2048,
-        dataclasses.replace(CALIB, t_coll=CALIB.t_elem), device_count=8,
-    )
-    assert base == pytest.approx(explicit)
-
-
-def test_calibrate_collective_positive_on_multidevice():
-    assert jax.device_count() >= 2  # conftest forces 8 host devices
-    t_coll = autotune.calibrate_collective(sample_dim=64, repeats=1)
-    assert t_coll > 0.0
 
 
 # ------------------------------------------------------- call-site caching
@@ -470,34 +456,84 @@ def test_warm_for_model_emits_site_tagged_telemetry():
     assert cache.entries and not any("|site:" in k for k in cache.entries)
 
 
-# ---------------------------------------------------------- mesh candidates
-def test_mesh_enumeration_and_dispatch():
-    """On a (data, model) mesh the registered strategies become candidates
-    and the selected one still matches the naive product."""
-    from repro.core.compat import make_mesh
+# ------------------------------------------------------------ the routes
+ROUTES = [
+    ("naive", "strassen", 0),
+    ("strassen", "strassen", 2),
+    ("winograd", "winograd", 2),
+    ("strassen_fused", "strassen", 2),
+    ("strassen_oot", "winograd", 1),
+]
 
-    if jax.device_count() < 2:
-        pytest.skip("needs the conftest multi-device host platform")
-    mesh = make_mesh((jax.device_count() // 2, 2), ("data", "model"))
-    cands = enumerate_candidates(512, 512, 512, min_dim=64, max_depth=2, mesh=mesh)
-    kinds = {c.kind for c in cands}
-    assert {
-        "naive",
-        "strassen",
-        "strassen_bfs_sharded",
-        "strassen_2d",
-        "strassen_fused_sharded",
-    } <= kinds
 
-    d = autotune.autotune(
-        512, 512, 512, min_dim=64, max_depth=1, mesh=mesh,
-        calibration=dataclasses.replace(CALIB, device_count=jax.device_count()),
-    )
-    x, w = _rand((512, 512)), _rand((512, 512))
-    got = autotune.execute(d.candidate, x, w, mesh=mesh)
+@pytest.mark.parametrize("kind,scheme,depth", ROUTES, ids=[r[0] for r in ROUTES])
+def test_decision_maps_to_its_route(kind, scheme, depth):
+    """route_for takes kind, depth and scheme from the decision and keeps
+    the caller's other fields; the backend it returns computes x @ w."""
+    decision = Decision(kind=kind, scheme=scheme, depth=depth, predicted_s=1e-3)
+    be = route_for(decision, _auto_backend(min_dim=16, precision="highest"))
+    assert (be.kind, be.depth, be.measure) == (kind, depth, False)
+    assert (be.min_dim, be.precision) == (16, "highest")
+    if kind != "naive":
+        assert be.scheme_name == scheme
+    x, w = _rand((128, 128)), _rand((128, 128))
+    assert be.effective_depth(128, 128, 128) == depth
+    got = matmul(x, w, be)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(x @ w), atol=3e-3, rtol=3e-3
     )
+
+
+def test_route_for_keeps_budget_and_cache_of_the_caller():
+    be = _auto_backend(device_budget=1 << 20, tuning_cache="t.json", measure=True)
+    cand = Candidate(kind="strassen_oot", scheme="strassen", depth=3)
+    got = route_for(cand, be)
+    assert got == dataclasses.replace(
+        be, kind="strassen_oot", depth=3, schemes=("strassen",), measure=False
+    )
+
+
+def test_measured_autotune_times_each_candidate_through_its_route(monkeypatch):
+    """measure=True times every top-k candidate as backend.matmul on the
+    backend route_for makes of it, and nothing else."""
+    routes = []
+    real = backend_mod.matmul
+
+    def spy(x, w, be=backend_mod.NAIVE_BACKEND, *a, **k):
+        routes.append((be.kind, be.schemes, be.depth, be.min_dim))
+        return real(x, w, be, *a, **k)
+
+    monkeypatch.setattr(backend_mod, "matmul", spy)
+    cands = enumerate_candidates(256, 256, 256, min_dim=64, max_depth=2)
+    top = sorted(cands, key=lambda c: predict_seconds(c, 256, 256, 256, CALIB))[:3]
+    d = autotune.autotune(
+        256, 256, 256, calibration=CALIB, min_dim=64, max_depth=2,
+        measure=True, top_k=3,
+    )
+    assert d.source == "measured"
+    assert set(routes) == {(c.kind, (c.scheme,), c.depth, 64) for c in top}
+
+
+def test_measured_oot_candidate_runs_at_the_routes_deepened_depth(monkeypatch):
+    """A strassen_oot candidate whose depth leaves no pipelined wave slot is
+    timed at the depth the route deepens to, as a decision for it runs."""
+    from repro.blocks import scheduler
+
+    m = k = n = 256
+    budget = scheduler.pipelined_leaf_bytes(m, k, n, 2, np.float32)
+    assert scheduler.pipelined_leaf_bytes(m, k, n, 1, np.float32) > budget
+    deepened = scheduler.min_depth_for_budget(m, k, n, budget, np.float32, pipelined=True)
+    depths = []
+    real = scheduler.strassen_oot_matmul
+
+    def spy(*a, depth, **k):
+        depths.append(depth)
+        return real(*a, depth=depth, **k)
+
+    monkeypatch.setattr(scheduler, "strassen_oot_matmul", spy)
+    cand = Candidate(kind="strassen_oot", scheme="strassen", depth=1)
+    autotune.measure_seconds(cand, m, k, n, jnp.float32, oot_budget=budget, repeats=1)
+    assert depths and set(depths) == {deepened} and deepened > cand.depth
 
 
 def test_fused_sharded_strategy_matches_matmul():
@@ -518,31 +554,9 @@ def test_fused_sharded_strategy_matches_matmul():
             np.testing.assert_allclose(
                 np.asarray(got), np.asarray(x @ w), atol=3e-3, rtol=3e-3
             )
-    cand = Candidate(kind="strassen_fused_sharded", scheme="strassen", depth=1)
+    be = MatmulBackend(kind="strassen_fused", depth=1, min_dim=1, mesh=mesh)
     x, w = _rand((256, 128)), _rand((128, 192))
-    got = autotune.execute(cand, x, w, mesh=mesh)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(x @ w), atol=3e-3, rtol=3e-3
-    )
-
-
-def test_mesh_selected_candidate_executes_on_awkward_shape():
-    """The reviewer repro: a mesh decision at a shape that is divisible by
-    2**depth but not by (row shards * 2**depth) must still execute."""
-    from repro.core.compat import make_mesh
-
-    if jax.device_count() < 2:
-        pytest.skip("needs the conftest multi-device host platform")
-    mesh = make_mesh((jax.device_count() // 2, 2), ("data", "model"))
-    calib = dataclasses.replace(
-        CALIB, t_flop=1e-9, t_elem=1e-12, t_coll=1e-12,
-        device_count=jax.device_count(),
-    )
-    d = autotune.autotune(
-        200, 200, 200, min_dim=1, max_depth=2, mesh=mesh, calibration=calib
-    )
-    x, w = _rand((200, 200)), _rand((200, 200))
-    got = autotune.execute(d.candidate, x, w, mesh=mesh)
+    got = matmul(x, w, be)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(x @ w), atol=3e-3, rtol=3e-3
     )

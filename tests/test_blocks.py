@@ -23,13 +23,13 @@ from repro.blocks.scheduler import (
 )
 from repro.core import autotune
 from repro.core.autotune import Calibration, Candidate
-from repro.core.backend import VALID_KINDS, MatmulBackend, matmul, resolve_auto
+from repro.core.backend import VALID_KINDS, MatmulBackend, matmul, resolve_auto, route_for
 from repro.core.coefficients import get_scheme, leaf_index_from_path, leaf_tag_path
 
 RNG = np.random.default_rng(7)
 
 CALIB = Calibration(
-    t_flop=1e-11, t_elem=1e-9, t_coll=4e-9, t_h2d=2e-9,
+    t_flop=1e-11, t_elem=1e-9, t_h2d=2e-9,
     device_kind="test", device_count=1,
 )
 
@@ -500,30 +500,6 @@ def test_oot_respects_min_dim_crossover_when_dense_fits():
     assert cands and all(c.kind == "strassen_oot" for c in cands)
 
 
-def test_oot_infeasibility_filter_covers_mesh_candidates():
-    """The dense-infeasible filter must drop mesh strategies too — the
-    budget models each device's memory, and a row-sharded fused leaf still
-    materializes blocks the filter declared impossible."""
-    import jax
-
-    from repro.core.compat import make_mesh
-
-    if jax.device_count() < 2:
-        pytest.skip("needs the conftest multi-device host platform")
-    mesh = make_mesh((jax.device_count() // 2, 2), ("data", "model"))
-    budget = 2 << 20  # < 3*512^2*4 dense working set
-    cands = autotune.enumerate_candidates(
-        512, 512, 512, min_dim=64, max_depth=2, mesh=mesh, oot_budget=budget
-    )
-    assert cands and all(c.kind == "strassen_oot" for c in cands)
-    # with a budget the dense set fits, mesh candidates stay enumerable
-    cands = autotune.enumerate_candidates(
-        512, 512, 512, min_dim=64, max_depth=2, mesh=mesh, oot_budget=16 << 20
-    )
-    kinds = {c.kind for c in cands}
-    assert "strassen_oot" in kinds and "strassen_bfs_sharded" in kinds
-
-
 def test_oot_only_candidates_when_dense_exceeds_budget():
     """When A+B+C cannot fit the budget at once, every on-device candidate
     is infeasible and enumeration keeps only the out-of-core family."""
@@ -544,8 +520,8 @@ def test_oot_only_candidates_when_dense_exceeds_budget():
 def test_oot_predicted_terms_include_t_h2d():
     cand = Candidate(kind="strassen_oot", scheme="strassen", depth=2)
     terms = autotune.predict_cost_terms(cand, 4096, 4096, 4096, CALIB)
-    assert set(terms) == {"t_flop", "t_elem", "t_coll", "t_h2d"}
-    assert terms["t_h2d"] > 0 and terms["t_coll"] == 0.0
+    assert set(terms) == {"t_flop", "t_elem", "t_h2d"}
+    assert terms["t_h2d"] > 0
     assert autotune.predict_seconds(cand, 4096, 4096, 4096, CALIB) == pytest.approx(
         sum(terms.values())
     )
@@ -600,18 +576,16 @@ def test_oot_overlap_discount_hides_staged_transfer_cost():
 
 
 def test_predict_terms_decomposition_sums_for_all_kinds():
-    calib = dataclasses.replace(CALIB, device_count=8)
     for cand in [
         Candidate(kind="naive"),
         Candidate(kind="strassen", scheme="strassen", depth=2),
+        Candidate(kind="winograd", scheme="winograd", depth=2),
         Candidate(kind="strassen_fused", scheme="strassen", depth=2),
-        Candidate(kind="strassen_bfs_sharded", scheme="strassen", depth=2),
-        Candidate(kind="strassen_fused_sharded", scheme="strassen", depth=1),
         Candidate(kind="strassen_oot", scheme="winograd", depth=3),
     ]:
-        terms = autotune.predict_cost_terms(cand, 2048, 2048, 2048, calib, device_count=8)
+        terms = autotune.predict_cost_terms(cand, 2048, 2048, 2048, CALIB)
         assert autotune.predict_seconds(
-            cand, 2048, 2048, 2048, calib, device_count=8
+            cand, 2048, 2048, 2048, CALIB
         ) == pytest.approx(sum(terms.values()))
 
 
@@ -624,12 +598,13 @@ def test_oot_execute_and_telemetry_terms():
     )
     (event,) = tel.events
     assert event.terms is not None and set(event.terms) == {
-        "t_flop", "t_elem", "t_coll", "t_h2d",
+        "t_flop", "t_elem", "t_h2d",
     }
-    # run the candidate small (same kind) to keep suite time sane
-    cand = Candidate(kind="strassen_oot", scheme=d.scheme, depth=1)
+    # run the decision's route small (same kind) to keep suite time sane
+    be = route_for(dataclasses.replace(d, depth=1), NAIVE_LEAVES)
+    assert (be.kind, be.scheme_name) == ("strassen_oot", d.scheme)
     a, b = _rand((96, 96)), _rand((96, 96))
-    got = autotune.execute(cand, jnp.asarray(a), jnp.asarray(b))
+    got = matmul(jnp.asarray(a), jnp.asarray(b), be)
     assert _rel_err(got, a @ b) < 2e-3
 
 

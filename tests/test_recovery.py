@@ -44,7 +44,7 @@ from repro.runtime.elastic import StragglerMonitor
 RNG = np.random.default_rng(11)
 
 CALIB = Calibration(
-    t_flop=1e-11, t_elem=1e-9, t_coll=4e-9, t_h2d=2e-9,
+    t_flop=1e-11, t_elem=1e-9, t_h2d=2e-9,
     device_kind="test", device_count=1,
 )
 

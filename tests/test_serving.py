@@ -62,7 +62,7 @@ def test_engine_scopes_autotune_telemetry(setup):
 
     cfg, params, _ = setup
     calib = Calibration(
-        t_flop=1e-11, t_elem=1e-9, t_coll=4e-9, t_h2d=2e-9,
+        t_flop=1e-11, t_elem=1e-9, t_h2d=2e-9,
         device_kind="test", device_count=1,
     )
     # pollute the process log the way a previous engine's resolutions would
