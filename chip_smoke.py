@@ -14,8 +14,10 @@ Phases (one process; each phase's gates fail the run):
                :func:`err_gate` of the Strassen levels the product ran.
 2. oot       — the tagged-block out-of-core runtime (kind='strassen_oot')
                on a 16384^2 bf16 product under a 1 GiB device budget:
-               >= 2 waves, modeled peak within budget, async pipeline on,
-               no degradation rung, the same error gate.
+               >= 2 waves, modeled peak within budget, the ladder's first
+               rung (device-resident subtrees where the budget holds one,
+               as 1 GiB holds a level-1 subtree here; else the async
+               pipeline), no degradation, the same error gate.
 3. spin      — SPIN block-recursive inversion (``backend.inverse``,
                kind='spin_oot') of a 4096^2 f32 SPD matrix under a budget
                that nests its multiplies out of core, at an explicit
@@ -203,7 +205,7 @@ def phase_oot(n: int = 16384, budget: int = GiB, seed: int = 1) -> None:
         f"phase2 n={n} budget={budget} A+B+C={operand_bytes} depth={st['depth']} "
         f"leaves={st['leaves']} waves={st['waves']} wave_size={st['wave_size']} "
         f"peak_device_bytes={st['peak_device_bytes']} prefetch={st['prefetch']} "
-        f"rung={st['rung']} degrades={st['degrades']} "
+        f"rung={st['rung']} subtree_level={st['subtree_level']} degrades={st['degrades']} "
         f"overlap_efficiency={st['overlap_efficiency']:.3f} rel_err={err:.3e}"
     )
     log(
@@ -212,7 +214,10 @@ def phase_oot(n: int = 16384, budget: int = GiB, seed: int = 1) -> None:
     )
     check(st["waves"] >= 2, f"phase2 ran {st['waves']} wave(s), need >= 2")
     check(st["peak_device_bytes"] <= budget, "phase2 peak device bytes over budget")
-    check(st["prefetch"] and st["rung"] == "pipeline", "phase2 async pipeline was off")
+    if st["subtree_level"] is None:
+        check(st["prefetch"] and st["rung"] == "pipeline", "phase2 async pipeline was off")
+    else:
+        check(st["rung"] == "subtree", f"phase2 subtrees ran on rung {st['rung']}")
     check(
         st["degrades"] == 0 and degrade.value == degrades_before,
         f"phase2 degraded: {st['degrade_events']}",

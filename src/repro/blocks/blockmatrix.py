@@ -57,13 +57,18 @@ def signed_block_sum(get_block, coefs: np.ndarray, acc_dtype) -> np.ndarray:
     buffer path) and summed in ``acc_dtype``, in ascending index order.
     Recompute replays a block bit-for-bit only because it runs this
     exact loop — keep any change to the ordering or fast paths here.
+    Device arrays pass through unconverted: the scheduler's on-device
+    subtrees trace this same loop.
     """
     acc = None
     for idx in range(len(coefs)):
         c = float(coefs[idx])
         if c == 0.0:
             continue
-        blk = np.asarray(get_block(idx)).astype(acc_dtype, copy=False)
+        blk = get_block(idx)
+        if isinstance(blk, np.ndarray) or not hasattr(blk, "astype"):
+            blk = np.asarray(blk)
+        blk = blk.astype(acc_dtype, copy=False)
         term = blk if c == 1.0 else (-blk if c == -1.0 else c * blk)
         acc = term if acc is None else acc + term
     assert acc is not None, "coefficient row is all zero"

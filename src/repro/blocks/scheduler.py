@@ -3,16 +3,27 @@
 This is the paper's level-parallel recursion (Fig. 2) re-targeted at the
 host/device memory hierarchy instead of a Spark cluster:
 
-* **divide** — for each level, every tree node's seven children are formed
-  by signed sums of the parent's quadrant blocks (Stark's
+* **divide** — for each host level, every tree node's seven children are
+  formed by signed sums of the parent's quadrant blocks (Stark's
   flatMapToPair/groupByKey/flatMap stage). These are host-side numpy adds
   streaming block-by-block through the :class:`~repro.blocks.blockmatrix
-  .BlockStore`, so host working set is O(block), not O(matrix).
-* **leaf** — the 7^q leaf products are batched into *waves* sized so that
-  (current wave operands + products, the previous wave's still-in-flight
-  working set — its operands stay pinned by the unfenced executions, not
-  just its un-fetched products — and the prefetched next-wave operands)
-  fit a configurable device-memory budget; see
+  .BlockStore`, so host working set is O(block), not O(matrix). The host
+  levels are 1..L, where L is the subtree level below (all q levels when
+  no subtree fits).
+* **subtrees** — when the device budget holds a whole level-L subtree
+  worked depth-first (:func:`subtree_level`: the shallowest L < q with
+  W(L) = sum over l = L..q of one level-l node's A + B + C within the
+  budget), each of the rank^L level-L nodes is staged once and one jitted
+  program divides, multiplies and combines everything below it in HBM,
+  one child at a time, on the store's own blocks; the level-L product's
+  blocks come back to the store. Each subtree is one wave of the spans
+  below. Runs with recovery or chaos keep the leaf waves: lineage replays
+  single stored leaves, and below L the store holds none.
+* **leaf** — otherwise the 7^q leaf products are batched into *waves*
+  sized so that (current wave operands + products, the previous wave's
+  still-in-flight working set — its operands stay pinned by the unfenced
+  executions, not just its un-fetched products — and the prefetched
+  next-wave operands) fit a configurable device-memory budget; see
   :func:`pipelined_leaf_bytes`. The wave loop is a 2-deep asynchronous
   pipeline
   keyed off JAX's async dispatch: wave k's products are left in flight
@@ -29,8 +40,8 @@ host/device memory hierarchy instead of a Spark cluster:
   :class:`OotStats.wave_events` and derive ``overlap_efficiency``.
 * **combine** — level-order bottom-up signed sums of the seven child
   products into each parent's quadrants (Stark's combine stage), again
-  host-side and block-streaming; child nodes are freed as soon as their
-  parent is built.
+  host-side and block-streaming, over host levels L..1; child nodes are
+  freed as soon as their parent is built.
 
 Peak device bytes are therefore bounded by the budget rather than the
 problem size — the paper's "matrices far larger than memory" regime with
@@ -74,6 +85,8 @@ __all__ = [
     "strassen_oot_matmul",
     "leaf_bytes",
     "pipelined_leaf_bytes",
+    "subtree_bytes",
+    "subtree_level",
     "min_depth_for_budget",
     "attach_stats_ring",
     "recent_oot_stats",
@@ -127,6 +140,32 @@ def pipelined_leaf_bytes(m: int, k: int, n: int, depth: int, dtype) -> int:
     """
     i, o = _leaf_inout_bytes(m, k, n, depth, dtype)
     return 2 * (i + o) + i
+
+
+def subtree_bytes(m: int, k: int, n: int, depth: int, level: int, dtype) -> int:
+    """W(level): device bytes of one level-``level`` subtree run on the chip.
+
+    Worked depth-first, one child at a time, the device holds one node's
+    A, B and C at every level from ``level`` down to the leaves:
+    sum over l = level..depth of (A_l + B_l + C_l), each at the
+    accumulation dtype (the sizing convention of :func:`_leaf_inout_bytes`).
+    """
+    i, o = _leaf_inout_bytes(m, k, n, depth, dtype)
+    return sum((i + o) << 2 * (depth - lv) for lv in range(level, depth + 1))
+
+
+def subtree_level(
+    m: int, k: int, n: int, depth: int, budget_bytes: int, dtype
+) -> Optional[int]:
+    """The shallowest level L < depth whose subtree fits the budget.
+
+    ``None`` when not even a level-(depth - 1) subtree fits: the run then
+    stages single leaves in waves.
+    """
+    for level in range(depth):
+        if subtree_bytes(m, k, n, depth, level, dtype) <= budget_bytes:
+            return level
+    return None
 
 
 def min_depth_for_budget(
@@ -232,6 +271,10 @@ class OotStats:
     corrupt_blocks: int = 0
     injected_faults: int = 0
     unrecovered_faults: int = 0
+    # Device-resident subtrees: the level L they start at (None on the
+    # leaf-wave path) and how many ran (rank**L).
+    subtree_level: Optional[int] = None
+    subtrees: int = 0
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -374,6 +417,23 @@ class _RunTrackingStore(BlockStore):
 
     def close(self) -> None:  # the caller owns the inner store
         pass
+
+
+# Jitted subtree programs by everything their trace reads: the leaf
+# routine and backend, the depth below L, the dtypes and the plan's
+# tables. Each run builds a fresh scheduler, so a warm-up and the runs
+# after it share one compiled program only through this table.
+_SUBTREE_PROGRAMS: Dict[tuple, object] = {}
+
+
+def _quadrant(grid: list, q: int) -> list:
+    """Row-major quadrant ``q`` (the tag alphabet's order) of a block grid."""
+    r, c = len(grid) // 2, len(grid[0]) // 2
+    return [row[(q % 2) * c : (q % 2 + 1) * c] for row in grid[(q // 2) * r : (q // 2 + 1) * r]]
+
+
+def _table(coef) -> Tuple[Tuple[float, ...], ...]:
+    return tuple(tuple(float(c) for c in row) for row in np.asarray(coef))
 
 
 class PlanScheduler:
@@ -594,6 +654,95 @@ class PlanScheduler:
 
         return _backend.matmul(a_dev, b_dev, self.backend, site="blocks.leaf")
 
+    def _subtree_program(self, levels: int, stage_dtype, acc_dtype):
+        """The jitted program C = A @ B for one subtree ``levels`` deep.
+
+        A, B and C are grids of the store's blocks (lists of rows), so
+        quadrants are sub-grids and no node is copied whole on the host
+        or the device. Depth-first, one child at a time: the plan's divide
+        rows form each child's blocks in ``acc_dtype`` through the host
+        divide's own loop (:func:`signed_block_sum`), leaf operands round
+        to ``stage_dtype`` just before :meth:`_leaf_matmul`, and each
+        child's product adds into C's blocks in the combine rows' order.
+        An optimization barrier puts child p + 1 after child p's combine,
+        so the device holds one node per level (:func:`subtree_bytes`).
+        """
+        import jax
+        import jax.numpy as jnp
+
+        a_name, b_name = self.plan.operands
+        key = (
+            type(self)._leaf_matmul, self.backend, levels,
+            np.dtype(stage_dtype).name, np.dtype(acc_dtype).name,
+            _table(self.plan.divide_coef[a_name]),
+            _table(self.plan.divide_coef[b_name]),
+            _table(self.plan.combine_coef),
+        )
+        program = _SUBTREE_PROGRAMS.get(key)
+        if program is not None:
+            return program
+        a_rows, b_rows, c_coef = key[5:]
+        leaf = self._leaf_matmul
+
+        def divide(grid, row):
+            return [
+                [
+                    signed_block_sum(lambda q: _quadrant(grid, q)[i][j], row, acc_dtype)
+                    for j in range(len(grid[0]) // 2)
+                ]
+                for i in range(len(grid) // 2)
+            ]
+
+        def node(a, b, levels):
+            if levels == 0:
+                prod = leaf(
+                    jnp.block(a).astype(stage_dtype), jnp.block(b).astype(stage_dtype)
+                ).astype(acc_dtype)
+                r, s = prod.shape[0] // len(a), prod.shape[1] // len(b[0])
+                return [
+                    [prod[i * r : (i + 1) * r, j * s : (j + 1) * s] for j in range(len(b[0]))]
+                    for i in range(len(a))
+                ]
+            c = [[None] * len(b[0]) for _ in a]
+            hr, hc = len(a) // 2, len(b[0]) // 2
+            for p in range(len(a_rows)):
+                if p:
+                    a, b, c = jax.lax.optimization_barrier((a, b, c))
+                prod = node(divide(a, a_rows[p]), divide(b, b_rows[p]), levels - 1)
+                for kq in range(tags.Q_BASE):
+                    coef = c_coef[kq][p]
+                    if coef == 0.0:
+                        continue
+                    for i, row in enumerate(prod):
+                        for j, blk in enumerate(row):
+                            # signed_block_sum's term and running sum, with
+                            # C's block holding the sum.
+                            term = blk if coef == 1.0 else (-blk if coef == -1.0 else coef * blk)
+                            ci, cj = (kq // 2) * hr + i, (kq % 2) * hc + j
+                            c[ci][cj] = term if c[ci][cj] is None else c[ci][cj] + term
+            return c
+
+        program = _SUBTREE_PROGRAMS[key] = jax.jit(lambda a, b: node(a, b, levels))
+        return program
+
+    def _grain(self, m: int, k: int, n: int, depth: int) -> Tuple[int, ...]:
+        """Recursion-aligned leaf dims and the block partition.
+
+        With an explicit block grain each leaf dim rounds up to a whole
+        number of blocks so every level's grid halves exactly. Returns
+        (lm, lk, ln, bam, bak, bbn).
+        """
+        lm, lk, ln = _leaf_dims(m, k, n, depth)
+        if self.block is None:
+            return lm, lk, ln, lm, lk, ln
+        bam, bak, bbn = min(self.block, lm), min(self.block, lk), min(self.block, ln)
+        return (
+            _ceil_div(lm, bam) * bam,
+            _ceil_div(lk, bak) * bak,
+            _ceil_div(ln, bbn) * bbn,
+            bam, bak, bbn,
+        )
+
     # -------------------------------------------------------------- the run
     def matmul(
         self,
@@ -609,10 +758,13 @@ class PlanScheduler:
         accepts, bfloat16 included). ``store`` picks the block residency:
         'dict' | 'arena' | 'memmap' or a ready :class:`BlockStore`.
 
-        Runs the graceful-degradation ladder: the configured mode first,
-        then — on an unrecovered fault (retries exhausted, lineage
-        recompute impossible) or device-OOM — synchronous staging, a
-        halved wave, and finally one level deeper recursion. Every rung
+        Runs the graceful-degradation ladder: device-resident subtrees
+        when :func:`subtree_level` finds a level whose subtree fits the
+        budget (and the run keeps no lineage: no ``recovery``, no
+        ``chaos``), then the configured leaf-wave mode, then — on an
+        unrecovered fault (retries exhausted, lineage recompute
+        impossible) or device-OOM — synchronous staging, a halved wave,
+        and finally one level deeper recursion. Every rung
         transition is a ``fault.degrade`` counter + instant span; the
         returned stats carry the completed rung and the transition log.
         Anything that is not a fault/OOM propagates unchanged from the
@@ -620,6 +772,20 @@ class PlanScheduler:
         """
         from repro.core.backend import is_oom_error
 
+        a = np.asarray(a)
+        b = np.asarray(b)
+        if a.ndim != 2 or b.ndim != 2 or a.shape[1] != b.shape[0]:
+            raise ValueError(f"bad matmul shapes {a.shape} @ {b.shape}")
+        # Lineage replays single stored leaves, and below the subtree level
+        # the store holds none: a run that keeps lineage stages leaves.
+        level = None
+        if not self.recovery and self.chaos is None:
+            lm, lk, ln = self._grain(a.shape[0], a.shape[1], b.shape[1], self.depth)[:3]
+            d = self.depth
+            level = subtree_level(
+                lm << d, lk << d, ln << d, d, self.budget_bytes,
+                np.result_type(a.dtype, b.dtype),
+            )
         # One flaky-leaf shim across the whole ladder: its dispatch-call
         # counter spans attempts, so "fail the Nth leaf multiply" windows
         # pass and the ladder can make progress.
@@ -631,6 +797,11 @@ class PlanScheduler:
                 seed=self.chaos.seed + 1,
             )
         rungs: List[Tuple[str, dict]] = []
+        if level is not None:
+            rungs.append(
+                ("subtree", dict(prefetch=False, wave_scale=1.0, depth=self.depth,
+                                 subtree_level=level))
+            )
         if self.prefetch:
             rungs.append(
                 ("pipeline", dict(prefetch=True, wave_scale=1.0, depth=self.depth))
@@ -686,8 +857,14 @@ class PlanScheduler:
         prefetch: bool,
         wave_scale: float,
         flaky: Optional[FlakyLeaf],
+        subtree_level: Optional[int] = None,
     ) -> Tuple[np.ndarray, OotStats]:
-        """One run of the level-order executor at a fixed ladder rung."""
+        """One run of the level-order executor at a fixed ladder rung.
+
+        ``subtree_level`` L runs host levels 1..L and each level-L subtree
+        on the device; ``None`` runs all levels on the host and stages
+        leaves in waves.
+        """
         import jax
 
         # Spans are the run's single timing source: OotStats (wave_events,
@@ -704,8 +881,6 @@ class PlanScheduler:
         mx = obs_metrics.get_metrics()
         a = np.asarray(a)
         b = np.asarray(b)
-        if a.ndim != 2 or b.ndim != 2 or a.shape[1] != b.shape[0]:
-            raise ValueError(f"bad matmul shapes {a.shape} @ {b.shape}")
         dtype = np.result_type(a.dtype, b.dtype)
         acc_dtype = np.result_type(dtype, np.float32)
         m, k = a.shape
@@ -718,21 +893,7 @@ class PlanScheduler:
         a_rows = self.plan.divide_coef[a_name]
         b_rows = self.plan.divide_coef[b_name]
 
-        # Recursion-aligned padded dims and the block partition. With an
-        # explicit block grain each leaf dim rounds up to a whole number of
-        # blocks so every level's grid halves exactly.
-        lm, lk, ln = _leaf_dims(m, k, n, depth)
-        if self.block is not None:
-            bam = min(self.block, lm)
-            bak = min(self.block, lk)
-            bbn = min(self.block, ln)
-            lm, lk, ln = (
-                _ceil_div(lm, bam) * bam,
-                _ceil_div(lk, bak) * bak,
-                _ceil_div(ln, bbn) * bbn,
-            )
-        else:
-            bam, bak, bbn = lm, lk, ln
+        lm, lk, ln, bam, bak, bbn = self._grain(m, k, n, depth)
         pm, pk, pn = lm << depth, lk << depth, ln << depth
 
         stage_dtype = (
@@ -767,6 +928,11 @@ class PlanScheduler:
             # (never below one leaf — single-leaf feasibility was checked
             # above, so this only trades throughput for headroom).
             wave_size = max(1, int(wave_size * wave_scale))
+        # The levels the host divides and combines; below them, one
+        # subtree per wave on the device.
+        host_levels = depth if subtree_level is None else subtree_level
+        if subtree_level is not None:
+            wave_size = 1
 
         # Divide/combine chains accumulate (and store) in acc_dtype; blocks
         # round at most once — operands at the staging cast, C at the final
@@ -846,7 +1012,7 @@ class PlanScheduler:
                 leaves=leaves, waves=0, wave_size=wave_size, prefetch=prefetch,
                 stage_dtype=stage_dtype.name,
                 budget_bytes=self.budget_bytes, per_leaf_bytes=per_leaf,
-                peak_device_bytes=0,
+                peak_device_bytes=0, subtree_level=subtree_level,
             )
 
             # --- ingest roots (edge/odd dims zero-extend to the padded grain).
@@ -864,7 +1030,7 @@ class PlanScheduler:
             # span per level, one tag-addressed span per tree node — the
             # exported trace's top lane reads as the recursion tree itself.
             div_span = tr.begin("oot.divide", cat="oot")
-            for level in range(depth):
+            for level in range(host_levels):
                 p_dtype = dtype if level == 0 else acc_dtype
                 with tr.span(
                     f"divide.L{level + 1}", cat="oot",
@@ -919,16 +1085,18 @@ class PlanScheduler:
             # it. Fetched product buffers are released the moment their
             # bytes land on host (donated into the host-side combine
             # accumulation), keeping the device peak at the budgeted
-            # pipelined slot.
+            # pipelined slot. With a subtree level, each wave is instead one
+            # level-L node whose whole subtree runs on the device.
+            leaf_list = list(tags.leaf_paths(host_levels, rank))
+            waves: List[List[Tuple[int, ...]]] = [
+                leaf_list[i : i + wave_size]
+                for i in range(0, len(leaf_list), wave_size)
+            ]
             leaf_span = tr.begin(
                 "oot.leaf_waves", cat="oot",
-                waves=_ceil_div(leaves, wave_size), wave_size=wave_size,
-                prefetch=prefetch,
+                waves=len(waves), wave_size=wave_size, prefetch=prefetch,
+                subtree_level=subtree_level,
             )
-            leaf_list = list(tags.leaf_paths(depth, rank))
-            waves: List[List[Tuple[int, ...]]] = [
-                leaf_list[i : i + wave_size] for i in range(0, leaves, wave_size)
-            ]
             # Per-wave phase spans, recorded on dedicated tracks so the
             # exported trace shows the pipeline's overlap as concurrent
             # lanes: wave k+1's "wave.stage" sits strictly inside wave k's
@@ -1109,43 +1277,120 @@ class PlanScheduler:
                     stats.host_store_peak_bytes, store.nbytes()
                 )
 
-            pending: Optional[Tuple[int, list]] = None
-            staged = stage(0) if (prefetch and waves) else None
-            for w_idx, wave in enumerate(waves):
-                current, staged = staged, None
-                if current is None:  # prefetch off: stage synchronously
-                    current = stage(w_idx)
-                outs = dispatch(w_idx, current)
-                current = None
-                # Modeled concurrent peak this iteration: wave k's working
-                # set + the previous wave's whole working set (un-fetched
-                # products, plus operands the in-flight executions may
-                # still pin) + the next wave's prefetched operands —
-                # matching the wave sizing above, so the budget bounds
-                # actual residency.
-                device_now = len(wave) * per_leaf
+            def run_subtree(w_idx: int, path: Tuple[int, ...]) -> None:
+                # One wave: stage the node's blocks once (a level-0 node is
+                # the input dtype in the store; the cast is the one the host
+                # divide's sums make), run its subtree, fetch C's blocks.
+                ws = wave_spans.setdefault(w_idx, {})
+                wsp = ws["stage"] = tr.begin(
+                    "wave.stage", cat="oot", track="oot.stage",
+                    wave=w_idx, size=1, tag=tags.to_string(path),
+                )
+                p_dtype = dtype if subtree_level == 0 else acc_dtype
+                na = self._node(store, a_name, path, (pm, pk), (bam, bak), p_dtype)
+                nb = self._node(store, b_name, path, (pk, pn), (bak, bbn), p_dtype)
+                host = [
+                    [
+                        [
+                            np.asarray(nd.block(i, j)).astype(acc_dtype, copy=False)
+                            for j in range(nd.grid[1])
+                        ]
+                        for i in range(nd.grid[0])
+                    ]
+                    for nd in (na, nb)
+                ]
+                staged = jax.device_put(host)
+                in_flight[w_idx] = jax.tree_util.tree_leaves(staged)
+                h2d = sum(blk.nbytes for blk in jax.tree_util.tree_leaves(host))
+                host = None
+                stats.h2d_bytes += h2d
+                mx.counter("oot.h2d_bytes").inc(h2d)
+                tr.end(wsp)
+                wsp = ws["dispatch"] = tr.begin(
+                    "wave.dispatch", cat="oot", track="oot.dispatch", wave=w_idx
+                )
+                # The operands free as the program completes.
+                out = program(*staged)
+                in_flight[w_idx] = jax.tree_util.tree_leaves(out)
+                staged = None
+                tr.end(wsp)
+                wsp = ws["fetch"] = tr.begin(
+                    "wave.fetch", cat="oot", track="oot.fetch", wave=w_idx
+                )
+                host = jax.device_get(out)  # the only fence
+                out = None
+                in_flight.pop(w_idx)
+                cn = self._node(store, c_name, path, (pm, pn), (bam, bbn), acc_dtype)
+                d2h = 0
+                for i, row in enumerate(host):
+                    for j, blk in enumerate(row):
+                        cn.put_block(i, j, blk)
+                        d2h += blk.nbytes
+                host = None
+                stats.d2h_bytes += d2h
+                mx.counter("oot.d2h_bytes").inc(d2h)
+                na.free()
+                nb.free()
+                tr.end(wsp)
+                tr.add_span(
+                    "wave.compute", ws["dispatch"].t1, wsp.t1, cat="oot",
+                    track=f"oot.compute/{w_idx % 2}", parent=leaf_span,
+                    wave=w_idx, size=1,
+                )
+                stats.waves += 1
+                stats.subtrees += 1
+                mx.counter("oot.subtrees").inc()
+                stats.host_store_peak_bytes = max(
+                    stats.host_store_peak_bytes, store.nbytes()
+                )
+
+            if subtree_level is not None:
+                program = self._subtree_program(
+                    depth - subtree_level, stage_dtype, acc_dtype
+                )
+                stats.peak_device_bytes = subtree_bytes(
+                    pm, pk, pn, depth, subtree_level, dtype
+                )
+                for w_idx, (path,) in enumerate(waves):
+                    run_subtree(w_idx, path)
+            else:
+                pending: Optional[Tuple[int, list]] = None
+                staged = stage(0) if (prefetch and waves) else None
+                for w_idx, wave in enumerate(waves):
+                    current, staged = staged, None
+                    if current is None:  # prefetch off: stage synchronously
+                        current = stage(w_idx)
+                    outs = dispatch(w_idx, current)
+                    current = None
+                    # Modeled concurrent peak this iteration: wave k's working
+                    # set + the previous wave's whole working set (un-fetched
+                    # products, plus operands the in-flight executions may
+                    # still pin) + the next wave's prefetched operands —
+                    # matching the wave sizing above, so the budget bounds
+                    # actual residency.
+                    device_now = len(wave) * per_leaf
+                    if pending is not None:
+                        device_now += len(pending[1]) * per_leaf
+                    if prefetch and w_idx + 1 < len(waves):
+                        device_now += len(waves[w_idx + 1]) * in_bytes
+                    stats.peak_device_bytes = max(stats.peak_device_bytes, device_now)
+                    if prefetch and w_idx + 1 < len(waves):
+                        # Stage the next wave's H2D while this wave's multiplies
+                        # run behind JAX's async dispatch — the staging calls'
+                        # host-side overhead executes on this thread while XLA's
+                        # worker pool computes wave k.
+                        staged = stage(w_idx + 1)
+                    if pending is not None:
+                        # D2H fence for wave k-1 while wave k is still in flight.
+                        drain(*pending)
+                        pending = None
+                    if prefetch:
+                        pending = (w_idx, outs)
+                    else:
+                        drain(w_idx, outs)
+                    outs = None
                 if pending is not None:
-                    device_now += len(pending[1]) * per_leaf
-                if prefetch and w_idx + 1 < len(waves):
-                    device_now += len(waves[w_idx + 1]) * in_bytes
-                stats.peak_device_bytes = max(stats.peak_device_bytes, device_now)
-                if prefetch and w_idx + 1 < len(waves):
-                    # Stage the next wave's H2D while this wave's multiplies
-                    # run behind JAX's async dispatch — the staging calls'
-                    # host-side overhead executes on this thread while XLA's
-                    # worker pool computes wave k.
-                    staged = stage(w_idx + 1)
-                if pending is not None:
-                    # D2H fence for wave k-1 while wave k is still in flight.
                     drain(*pending)
-                    pending = None
-                if prefetch:
-                    pending = (w_idx, outs)
-                else:
-                    drain(w_idx, outs)
-                outs = None
-            if pending is not None:
-                drain(*pending)
             tr.end(leaf_span)
             stats.leaf_s = leaf_span.duration
             # Wave telemetry is DERIVED from the recorded spans (public
@@ -1168,7 +1413,7 @@ class PlanScheduler:
 
             # --- combine: level-order bottom-up, freeing children as we go.
             comb_span = tr.begin("oot.combine", cat="oot")
-            for level in reversed(range(depth)):
+            for level in reversed(range(host_levels)):
                 with tr.span(
                     f"combine.L{level + 1}", cat="oot",
                     level=level + 1, nodes=rank**level,

@@ -501,8 +501,9 @@ class SolveScheduler:
         stats.unrecovered_faults += sub.unrecovered_faults
         stats.degrades += sub.degrades
         stats.degrade_events.extend(sub.degrade_events)
+        stats.subtrees += sub.subtrees
         # Keep the *worst* rung any sub-run completed on.
-        order = ["pipeline", "sync", "halved-wave", "deeper"]
+        order = ["subtree", "pipeline", "sync", "halved-wave", "deeper"]
         if order.index(sub.rung) > order.index(stats.rung):
             stats.rung = sub.rung
         # Transfer-time-weighted overlap aggregate across sub-runs.

@@ -256,6 +256,7 @@ def test_oot_bf16_parity_within_1e2():
 
 
 def test_oot_block_grain_and_prefetch_off():
+    # a.nbytes holds a level-1 subtree (W(1) = 34,560 B at 96^2, depth 2)
     a, b = _rand((96, 96)), _rand((96, 96))
     out, stats = strassen_oot_matmul(
         a, b, depth=2, budget_bytes=a.nbytes, block=8,
@@ -263,6 +264,21 @@ def test_oot_block_grain_and_prefetch_off():
     )
     assert _rel_err(out, a @ b) < 2e-3
     assert not stats.prefetch
+    assert stats.subtree_level == 1 and stats.rung == "subtree"
+
+
+def test_oot_block_grain_and_prefetch_off_leaf_waves():
+    """Below the subtree threshold: synchronous waves of three leaves."""
+    a, b = _rand((96, 96)), _rand((96, 96))
+    budget = 3 * leaf_bytes(96, 96, 96, 2, a.dtype)
+    out, stats = strassen_oot_matmul(
+        a, b, depth=2, budget_bytes=budget, block=8,
+        backend=NAIVE_LEAVES, prefetch=False,
+    )
+    assert _rel_err(out, a @ b) < 2e-3
+    assert not stats.prefetch
+    assert stats.subtree_level is None and stats.rung == "sync"
+    assert stats.wave_size == 3 and stats.waves == 17
 
 
 def test_oot_budget_too_small_raises_with_min_depth():

@@ -405,15 +405,16 @@ def test_overlap_efficiency_parity_with_wave_events():
         assert e["dispatch_end"] <= e["fetch_end"] and e["fetch_start"] <= e["fetch_end"]
 
 
-def _oot_small_problem(budget_slots=12):
-    """256^2 bf16 operands and a depth-2 budget with room for
-    ``budget_slots`` pipelined leaf slots: 12 gives waves of 4, so five
-    waves."""
+def _oot_small_problem(budget_slots=12, n=256):
+    """n^2 bf16 operands and a depth-2 budget with room for
+    ``budget_slots`` pipelined leaf slots (131,072 B at n = 256). 12 holds
+    the whole product's subtree (W(0) = 7.875 slots): one device-resident
+    subtree. 2 to 7 hold a level-1 subtree (W(1) = 1.875 slots): seven of
+    them, one wave each. 1 holds no subtree: 49 leaves in waves of one."""
     import ml_dtypes
 
     from repro.blocks.scheduler import pipelined_leaf_bytes
 
-    n = 256
     rng = np.random.default_rng(1)
     a = rng.standard_normal((n, n)).astype(ml_dtypes.bfloat16)
     b = rng.standard_normal((n, n)).astype(ml_dtypes.bfloat16)
@@ -428,10 +429,10 @@ def _oot_small_run(a, b, budget):
     )
 
 
-def test_oot_phases_on_the_profiler_clock(tmp_path):
+def _phases_on_the_profiler_clock(tmp_path, budget_slots, n=256):
     import jax
 
-    problem = _oot_small_problem()
+    problem = _oot_small_problem(budget_slots, n)
     _oot_small_run(*problem)  # compile the leaf outside the trace
     with _profiled(tmp_path) as events:
         with jax.profiler.TraceAnnotation("test.call"):
@@ -450,10 +451,28 @@ def test_oot_phases_on_the_profiler_clock(tmp_path):
     # The root span is left to the caller's mark.
     assert not _named(events, "oot.matmul")
     assert len(_named(events, "wave.fetch")) == stats.waves
+    return stats
 
 
-def _divide_bytes_closed_form(n, depth, in_item, acc_item, reads=12, rank=7):
-    """Bytes the divide of both square operands reads and writes.
+def test_oot_phases_on_the_profiler_clock(tmp_path):
+    """Seven level-1 subtrees, one wave each. At 1024^2: at 256^2 the
+    subtrees finish in a few ms, and the profiler's own cost on the
+    Python bookkeeping around the phases is then over 5% of the call."""
+    stats = _phases_on_the_profiler_clock(tmp_path, budget_slots=4, n=1024)
+    assert stats.subtree_level == 1 and stats.waves == 7
+
+
+def test_oot_phases_on_the_profiler_clock_leaf_waves(tmp_path):
+    """Below the subtree threshold: the leaf waves, 49 of them (1024^2 for
+    the same reason as above)."""
+    stats = _phases_on_the_profiler_clock(tmp_path, budget_slots=1, n=1024)
+    assert stats.subtree_level is None and stats.waves == 49
+
+
+def _divide_bytes_closed_form(n, levels, in_item, acc_item, reads=12, rank=7):
+    """Bytes the host divide of both square operands reads and writes over
+    its ``levels`` levels (the subtree level, or the depth when no subtree
+    fits the budget).
 
     Level l (1-based) has rank**(l - 1) parents. Each reads ``reads``
     quadrants (the nonzero coefficients of the plan's rows: 12 for
@@ -462,7 +481,7 @@ def _divide_bytes_closed_form(n, depth, in_item, acc_item, reads=12, rank=7):
     every later read is in the accumulation dtype.
     """
     total = 0
-    for level in range(1, depth + 1):
+    for level in range(1, levels + 1):
         parent_item = in_item if level == 1 else acc_item
         quad = (n >> level) ** 2
         total += rank ** (level - 1) * quad * (reads * parent_item + rank * acc_item)
@@ -470,13 +489,29 @@ def _divide_bytes_closed_form(n, depth, in_item, acc_item, reads=12, rank=7):
 
 
 def test_divide_bytes_matches_closed_form():
+    # The 12-slot budget holds the whole product's subtree: the host
+    # divides nothing.
     _, stats = _oot_small_run(*_oot_small_problem())
-    assert stats.divide_bytes == _divide_bytes_closed_form(256, 2, 2, 4) == 6_062_080
-    # The benchmark's out-of-core size: 8192^2 bf16, depth 2.
+    assert stats.subtree_level == 0
+    assert stats.divide_bytes == _divide_bytes_closed_form(256, 0, 2, 4) == 0
+    # The benchmark's out-of-core size: 8192^2 bf16, depth 2, all levels
+    # on the host, and level 1 only (its 256 MiB budget gives L = 1).
     assert _divide_bytes_closed_form(8192, 2, 2, 4) == 6_207_569_920
+    assert _divide_bytes_closed_form(8192, 1, 2, 4) == 1_744_830_464
     phases = sum(getattr(stats, field) for field, _ in _OOT_PHASES)
     assert stats.total_s >= phases
     assert all(getattr(stats, field) > 0 for field, _ in _OOT_PHASES)
+
+
+@pytest.mark.parametrize(
+    "budget_slots, levels, want", [(4, 1, 1_703_936), (1, 2, 6_062_080)]
+)
+def test_divide_bytes_of_host_levels(budget_slots, levels, want):
+    """A level-1 subtree leaves the host level 1; below the threshold
+    the host divides both levels."""
+    _, stats = _oot_small_run(*_oot_small_problem(budget_slots))
+    assert (stats.subtree_level or stats.depth) == levels
+    assert stats.divide_bytes == _divide_bytes_closed_form(256, levels, 2, 4) == want
 
 
 def test_oot_stats_ring_isolation():

@@ -241,3 +241,23 @@ def test_local_route_32768_bf16_does_not_fit_one_chip(one_chip, compiled_leaf):
     x = _spec((32768, 32768), jnp.bfloat16, one_chip)
     with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
         jax.jit(_fused_d2()).lower(x, x).compile()
+
+
+def test_oot_subtree_program_fits_its_modelled_budget(one_chip):
+    """The out-of-core cell's device program: one level-1 subtree of an
+    8192^2 depth-2 product, a 2x2 grid of 2048^2 f32 blocks per operand.
+    Its arguments, output and temporaries stay within W(1), the bytes the
+    level rule charges against the cell's 256 MiB budget."""
+    from repro.blocks.scheduler import PlanScheduler, subtree_bytes, subtree_level
+
+    budget = 268_435_456
+    assert subtree_level(8192, 8192, 8192, 2, budget, jnp.bfloat16) == 1
+    sched = PlanScheduler(
+        depth=2, budget_bytes=budget, backend=backend.MatmulBackend(kind="naive")
+    )
+    program = sched._subtree_program(1, np.dtype(np.float32), np.dtype(np.float32))
+    grid = [[_spec((2048, 2048), jnp.float32, one_chip)] * 2] * 2
+    mem = program.lower(grid, grid).compile().memory_analysis()
+    assert mem.argument_size_in_bytes == 2 * 4096 * 4096 * 4
+    total = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    assert total <= subtree_bytes(8192, 8192, 8192, 2, 1, jnp.bfloat16) == 251_658_240
